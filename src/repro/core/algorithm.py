@@ -32,7 +32,6 @@ Two equivalent implementations are provided:
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Callable, NamedTuple, Optional
 
 import jax
@@ -160,7 +159,7 @@ def make_local_fn(
     if use_fused_kernel:
         from repro.kernels import ops as kops
 
-        step_impl = partial(kops.fused_local_update_step, interpret_ok=True)
+        step_impl = kops.fused_local_update_step
 
     def local_fn(state: DProxState, batches: Batch):
         # numpy batch leaves must become jnp before traced-index selection

@@ -85,6 +85,7 @@ from repro.obs import trace as _trace
 from repro.exec.stages import (Asynchrony, Cohort, DownlinkComm, Placement,
                                StageStack, UplinkComm, sink_blockers)
 from repro.exec.suppliers import BatchSupplier, as_supplier
+from repro.utils import tree as tu
 
 Batch = Any
 
@@ -111,8 +112,10 @@ class EngineConfig:
                      the historical round-at-a-time loops exactly.
     jit            : disable to run the round function eagerly (debugging);
                      forces chunk_rounds=1 and composes with no stages.
-    donate_state   : donate the carry into the compiled call.  Ignored on
-                     CPU, where XLA does not implement donation.
+    donate_state   : donate the carry into the compiled call.  Applies on
+                     accelerator backends only (:func:`donates`); a sink
+                     that keeps a carry array past the next chunk must
+                     copy it (``SnapshotStore.engine_sink`` does).
     participation  : if set, the fraction of clients active each round
                      (uniform sampling without replacement, >= 1 client).
                      Requires a round function with an ``active`` argument;
@@ -398,6 +401,13 @@ class EngineConfig:
                 self.edges if self.edges is not None else 1)
 
 
+def donates() -> bool:
+    """Whether the engine donates its buffers into the compiled call: on
+    accelerator backends, where the carry is what bounds device memory.
+    The CPU path keeps every earlier carry alive."""
+    return jax.default_backend() != "cpu"
+
+
 def rounds_to_boundary(r: int, every: int, total: int) -> int:
     """Rounds from ``r`` to the next multiple of ``every``, capped at
     ``total`` -- the segment length drivers hand to :meth:`RoundEngine.run`
@@ -597,8 +607,12 @@ class RoundEngine:
     # -- state ------------------------------------------------------------
 
     def init(self, params0):
-        """Algorithm state, placed on the stack's devices."""
-        state = self.algorithm.init(params0, self.n_clients)
+        """Algorithm state, placed on the stack's devices.  It shares no
+        buffer with ``params0``: the algorithms keep ``params0`` itself as
+        their first server state, and the compiled call donates the state,
+        which would delete the caller's parameters."""
+        state = tu.tree_copy_shared(
+            self.algorithm.init(params0, self.n_clients), params0)
         if self.stack.placement is not None:
             state = jax.device_put(state, self.state_shardings(state))
         return state
@@ -734,7 +748,7 @@ class RoundEngine:
         cfg = self.config
         stack = self.stack
         chunk_fn = self._make_chunk_fn()
-        accel = cfg.jit and jax.default_backend() != "cpu"
+        accel = cfg.jit and donates()
         donate = cfg.donate_state and accel
         donate_argnums = (0,) if donate else ()
         if self._donate_batches and accel:
@@ -865,7 +879,9 @@ class RoundEngine:
         The tap rides the jit'd split path only: stages that re-route the
         uplink off the scan's straight line (asynchrony's report buffers,
         cohort residency, partial participation, placement) and the eager /
-        fused-``round_fn`` paths raise.  Pass ``None`` to remove the sink.
+        fused-``round_fn`` paths raise.  ``state`` is the carry the next
+        chunk's call donates on an accelerator: a sink that reads it later
+        copies what it needs first.  Pass ``None`` to remove the sink.
         """
         if sink is not None:
             if not self.stack.split:
@@ -908,8 +924,9 @@ class RoundEngine:
         every chunk boundary, so it composes with every stage combination
         (async, cohort, participation, placement, eager) except the
         protocol form (see :func:`repro.exec.stages.sink_blockers`).  The
-        sink must not mutate ``state``; snapshots published from it share
-        the engine's buffers.  Pass ``None`` to remove.
+        sink must not mutate ``state``, and must copy any array of it that
+        it keeps: on an accelerator the next chunk's call donates the
+        carry, which deletes its buffers.  Pass ``None`` to remove.
         """
         if sink is not None:
             blockers = sink_blockers(self.stack,
@@ -934,7 +951,7 @@ class RoundEngine:
         flag is actually baked into it (accelerator + jit)."""
         if donate == self._donate_batches:
             return
-        if self.config.jit and jax.default_backend() != "cpu":
+        if self.config.jit and donates():
             self._chunked_call = None
         self._donate_batches = donate
 

@@ -78,6 +78,7 @@ import numpy as np
 from repro.comm import wire
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.utils.compile_cache import use_compile_cache
 
 __all__ = ["RuntimeArgs", "run_local", "run_server", "run_worker",
            "run_replica", "run_pair", "shard_bounds", "add_runtime_args"]
@@ -299,14 +300,22 @@ class _UplinkSender:
     # -- the engine-facing callback --------------------------------------
 
     def sink(self, start_round: int, msgs, state) -> None:
+        from repro.exec.engine import server_state_fields
+        from repro.utils.tree import tree_copy_shared
+
         if self._err is not None:
             raise RuntimeError("uplink sender died") from self._err
         with obs_trace.timed("uplink/wait", "uplink",
                              start_round=int(start_round)) as tm:
+            # copied here, on the compute thread: on an accelerator the
+            # engine donates ``state`` to the next chunk's call, which
+            # deletes its buffers before the sender thread reads them
+            committed = tree_copy_shared(
+                server_state_fields(self.algorithm, state), state)
             if self._q is None:
-                self._ship(start_round, msgs, state)
+                self._ship(start_round, msgs, committed)
             else:
-                self._q.put((start_round, msgs, state))
+                self._q.put((start_round, msgs, committed))
         self._m_wait.add(tm.seconds)
 
     # -- internals --------------------------------------------------------
@@ -324,7 +333,7 @@ class _UplinkSender:
             finally:
                 self._q.task_done()
 
-    def _ship(self, start_round: int, msgs, state) -> None:
+    def _ship(self, start_round: int, msgs, committed) -> None:
         import jax
 
         t0 = time.perf_counter()
@@ -348,7 +357,7 @@ class _UplinkSender:
                 "rounds": int(c),
                 "base_version": int(self.base_version),
                 "msgs": packed,
-                "committed": _server_fields(self.algorithm, state),
+                "committed": jax.tree_util.tree_map(np.asarray, committed),
             }
             nb = wire.send_frame(self.sock, wire.T_CHUNK, frame)
             sp.set(nbytes=nb, rounds=int(c))
@@ -866,16 +875,32 @@ def _spawn(a: RuntimeArgs, role: str, rank: int = 0) -> subprocess.Popen:
     argv = [sys.executable, "-m", "repro.fed.runtime",
             "--role", role, "--rank", str(rank)] + _to_argv(a)
     env = dict(os.environ)
-    env["PYTHONPATH"] = (os.pathsep.join(
-        [p for p in [os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__)))] if p]
-        + ([env["PYTHONPATH"]] if "PYTHONPATH" in env else [])))
+    # the directory holding the ``repro`` package (``src/``)
+    src = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + ([env["PYTHONPATH"]] if "PYTHONPATH" in env else []))
+    if role != "worker":
+        # the server and the replicas do host work only: keep them off the
+        # accelerator, which belongs to the worker processes
+        env["JAX_PLATFORMS"] = "cpu"
     return subprocess.Popen(argv, env=env)
 
 
 def run_pair(a: RuntimeArgs) -> dict:
     """Server subprocess + ``a.workers`` workers (rank 0 runs in this
-    process so its report and exceptions surface directly)."""
+    process so its report and exceptions surface directly).
+
+    On a TPU backend only this process may hold the chips: libtpu lets one
+    process per host load it (``/tmp/libtpu_lockfile``), so the run takes
+    exactly one worker there and refuses more."""
+    import jax
+
+    if a.workers > 1 and jax.default_backend() == "tpu":
+        raise ValueError(
+            f"--workers {a.workers} needs {a.workers} processes on the TPU, "
+            "but libtpu admits one process per host; run one worker on the "
+            "TPU, or the workers on the CPU with JAX_PLATFORMS=cpu")
     if a.port == 0:
         a.port = _free_port(a.host)
     procs = [_spawn(a, "server")]
@@ -1016,6 +1041,7 @@ def main(argv=None) -> int:
     add_runtime_args(ap)
     ns = ap.parse_args(argv)
     a = _from_ns(ns)
+    use_compile_cache()
 
     if ns.role == "local":
         res = run_local(a)

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
 These adapt parameter pytrees / flat planes / GQA head layouts to the
-kernels' tiled layouts, and select interpret mode automatically on non-TPU
-backends so the same call sites work on CPU (tests) and TPU (production).
+kernels' tiled layouts.  ``interpret=None`` compiles the kernel on a TPU
+backend and interprets it anywhere else, so the same call sites work on
+the CPU (tests) and the TPU (production); a run that must not fall back
+to the CPU checks the platform itself (``chip_smoke.py``).
 
 Since the flat-plane refactor the pytree entry points flatten the whole
 tree onto ONE contiguous lane-padded buffer (:mod:`repro.core.plane`) and
@@ -11,8 +13,6 @@ leaf: the kernels see one tiled layout, and tiny leaves (biases, norms)
 stop costing a full tile each.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -27,21 +27,11 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _block_rows_for(rows: int, block_rows: int) -> int:
-    """The largest kernel block height <= block_rows dividing ``rows``."""
-    b = min(block_rows, rows)
-    while rows % b:
-        b -= 1
-    return b
-
-
-def _as_tiles(flat_plane, block_rows):
-    """(\\*batch, d_pad) plane -> ((\\*batch, rows, LANES) tiles, block)."""
+def _as_tiles(flat_plane):
+    """(\\*batch, d_pad) plane -> (\\*batch, rows, LANES) tiles."""
     d_pad = flat_plane.shape[-1]
     assert d_pad % LANES == 0, d_pad
-    rows = d_pad // LANES
-    tiles = flat_plane.reshape(flat_plane.shape[:-1] + (rows, LANES))
-    return tiles, _block_rows_for(rows, block_rows)
+    return flat_plane.reshape(flat_plane.shape[:-1] + (d_pad // LANES, LANES))
 
 
 def fused_local_update(z_hat, grads, c, eta, thresh, *, interpret=None,
@@ -93,15 +83,13 @@ def _fused_local_update_per_leaf(z_hat, grads, c, eta, thresh, *, interpret,
             jax.tree_util.tree_unflatten(treedef, out_z))
 
 
-def fused_local_update_step(reg, eta, t, z_hat, grads, c, *,
-                            interpret_ok=True):
+def fused_local_update_step(reg, eta, t, z_hat, grads, c):
     """Drop-in for repro.core.algorithm.local_update_step when reg is L1."""
     from repro.core.prox import L1
 
     assert isinstance(reg, L1), "fused kernel path requires the L1 regularizer"
     thresh = (t + 1) * eta * reg.lam
-    return fused_local_update(z_hat, grads, c, eta, thresh,
-                              interpret=None if interpret_ok else False)
+    return fused_local_update(z_hat, grads, c, eta, thresh)
 
 
 # ---------------------------------------------------------------------------
@@ -116,9 +104,9 @@ def plane_threshold_select(flat_plane, thresh, *, interpret=None,
     fused pass; the k-th values come from one ``lax.top_k`` on the plane).
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
-    tiles, b = _as_tiles(flat_plane, block_rows)
-    out = plane_ops.threshold_select_3d(tiles, thresh, interpret=interpret,
-                                        block_rows=b)
+    out = plane_ops.threshold_select_3d(_as_tiles(flat_plane), thresh,
+                                        interpret=interpret,
+                                        block_rows=block_rows)
     return out.reshape(flat_plane.shape)
 
 
@@ -127,21 +115,19 @@ def plane_quantize(flat_plane, u, scale, levels: int, *, interpret=None,
     """Fused stochastic uniform quantization on a (clients, d_pad) plane
     given uniform draws ``u`` and per-client ``scale`` magnitudes."""
     interpret = (not _on_tpu()) if interpret is None else interpret
-    tiles, b = _as_tiles(flat_plane, block_rows)
-    utiles, _ = _as_tiles(u, block_rows)
-    out = plane_ops.quantize_3d(tiles, utiles, scale, levels,
-                                interpret=interpret, block_rows=b)
+    out = plane_ops.quantize_3d(_as_tiles(flat_plane), _as_tiles(u), scale,
+                                levels, interpret=interpret,
+                                block_rows=block_rows)
     return out.reshape(flat_plane.shape)
 
 
-def plane_weighted_commit(buf, w, *, interpret=None,
-                          block_rows=fused_prox.BLOCK_ROWS):
+def plane_weighted_commit(buf, w, *, interpret=None, block_rows=None):
     """Staleness-weighted commit reduction ``sum_i w_i * buf_i`` over the
     client axis of a (clients, d_pad) report-buffer plane, in one pass."""
     interpret = (not _on_tpu()) if interpret is None else interpret
-    tiles, b = _as_tiles(buf, block_rows)
-    out = plane_ops.weighted_commit_3d(tiles, w, interpret=interpret,
-                                       block_rows=b)
+    out = plane_ops.weighted_commit_3d(_as_tiles(buf), w,
+                                       interpret=interpret,
+                                       block_rows=block_rows)
     return out.reshape(buf.shape[-1:])
 
 

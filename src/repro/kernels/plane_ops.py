@@ -20,11 +20,15 @@ instead of one small op per pytree leaf:
     :mod:`repro.sched.aggregator`'s commit step performs per leaf today).
 
 TPU mapping: planes are reshaped to ``(clients, rows, 128)`` lanes; each
-grid step processes one client's ``(BLOCK_ROWS, 128)`` tile resident in
+grid step processes one client's ``(block_rows, 128)`` tile resident in
 VMEM (the commit kernel processes all clients of one tile column, since it
-reduces over them).  Per-client scalars (thresholds, quantization scales,
-commit weights) ride in SMEM.  Public entry points with automatic
-interpret-mode selection and padding live in :mod:`repro.kernels.ops`.
+reduces over them, so its block height shrinks as the client count grows:
+see :func:`commit_block_rows`).  ``rows`` need not divide by the block
+height: the grid is ``cdiv(rows, block_rows)`` and the last block is
+ragged (its out-of-range rows are never written).  Per-client scalars
+(thresholds, quantization scales, commit weights) ride in SMEM.  Public
+entry points with automatic interpret-mode selection live in
+:mod:`repro.kernels.ops`.
 """
 from __future__ import annotations
 
@@ -36,6 +40,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_prox import BLOCK_ROWS, LANES
+
+#: VMEM bytes one commit input block may take; Pallas double-buffers it,
+#: so the kernel's footprint stays well inside v5e's 16 MiB scoped default
+COMMIT_VMEM_BYTES = 4 << 20
 
 
 def _threshold_kernel(thresh_ref, x_ref, out_ref):
@@ -49,11 +57,12 @@ def _threshold_kernel(thresh_ref, x_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def threshold_select_3d(x, thresh, *, interpret=False,
                         block_rows=BLOCK_ROWS):
-    """Core call on ``x``: (n, R, 128) with R % block_rows == 0;
-    ``thresh``: (n,) f32 per-client magnitude thresholds."""
+    """Core call on ``x``: (n, R, 128); ``thresh``: (n,) f32 per-client
+    magnitude thresholds."""
     n, rows, lanes = x.shape
-    assert lanes == LANES and rows % block_rows == 0, x.shape
-    grid = (n, rows // block_rows)
+    assert lanes == LANES, x.shape
+    block_rows = min(block_rows, rows)
+    grid = (n, pl.cdiv(rows, block_rows))
     spec = pl.BlockSpec((1, block_rows, LANES), lambda i, j: (i, j, 0))
     return pl.pallas_call(
         _threshold_kernel,
@@ -84,8 +93,9 @@ def quantize_3d(x, u, scale, levels: int, *, interpret=False,
     """Core call on ``x``/``u``: (n, R, 128); ``scale``: (n,) per-client max
     magnitudes; ``levels``: static quantization level count."""
     n, rows, lanes = x.shape
-    assert lanes == LANES and rows % block_rows == 0, x.shape
-    grid = (n, rows // block_rows)
+    assert lanes == LANES, x.shape
+    block_rows = min(block_rows, rows)
+    grid = (n, pl.cdiv(rows, block_rows))
     spec = pl.BlockSpec((1, block_rows, LANES), lambda i, j: (i, j, 0))
     return pl.pallas_call(
         functools.partial(_quantize_kernel, levels=levels),
@@ -106,13 +116,30 @@ def _commit_kernel(w_ref, buf_ref, out_ref, *, n_clients):
     out_ref[...] = acc.astype(out_ref.dtype)
 
 
+def commit_block_rows(n_clients: int, rows: int, itemsize: int,
+                      block_rows: int) -> int:
+    """Block height of :func:`weighted_commit_3d` for ``n_clients``.
+
+    One block holds the tile column of every client, so its height is
+    what keeps ``n_clients * height * 128 * itemsize`` within
+    :data:`COMMIT_VMEM_BYTES`: a multiple of 8 (the sublane tiling), capped
+    by ``block_rows`` (callers' explicit tiling) and by ``rows``.
+    """
+    fit = max(8, COMMIT_VMEM_BYTES // (n_clients * LANES * itemsize) // 8 * 8)
+    return min(block_rows, fit, rows)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
-def weighted_commit_3d(buf, w, *, interpret=False, block_rows=BLOCK_ROWS):
+def weighted_commit_3d(buf, w, *, interpret=False, block_rows=None):
     """Core call on ``buf``: (n, R, 128), ``w``: (n,) -> (R, 128) weighted
-    sum over clients (one tile column of all clients resident per step)."""
+    sum over clients (one tile column of all clients resident per step).
+    ``block_rows`` caps the height :func:`commit_block_rows` picks."""
     n, rows, lanes = buf.shape
-    assert lanes == LANES and rows % block_rows == 0, buf.shape
-    grid = (rows // block_rows,)
+    assert lanes == LANES, buf.shape
+    block_rows = commit_block_rows(
+        n, rows, buf.dtype.itemsize,
+        BLOCK_ROWS if block_rows is None else block_rows)
+    grid = (pl.cdiv(rows, block_rows),)
     in_spec = pl.BlockSpec((n, block_rows, LANES), lambda j: (0, j, 0))
     out_spec = pl.BlockSpec((block_rows, LANES), lambda j: (j, 0))
     return pl.pallas_call(

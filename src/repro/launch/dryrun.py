@@ -244,7 +244,7 @@ def _compile(builder, cfg, shape, mesh, multi_pod, **kw):
 
 
 def _costs(compiled):
-    ca = roof.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     coll = sum(c[3] for c in roof.parse_collectives(txt))
     return (float(ca.get("flops", 0.0)),

@@ -13,27 +13,13 @@ import) sees 512 placeholder devices.
 from __future__ import annotations
 
 import jax
-
-
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist from jax 0.5.x;
-    on older runtimes every axis is Auto-typed anyway, so omitting the kwarg
-    is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(
-        shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-    )
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(n_devices: int | None = None, model: int = 2):
@@ -41,4 +27,5 @@ def make_debug_mesh(n_devices: int | None = None, model: int = 2):
     multi-device integration tests (8 CPU devices)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    return make_mesh_compat((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

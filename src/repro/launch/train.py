@@ -53,6 +53,7 @@ import numpy as np
 
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.utils.compile_cache import use_compile_cache
 
 from repro.checkpoint import ckpt
 from repro.configs import registry
@@ -82,6 +83,14 @@ def scale_config(cfg, scale: str):
                 logit_softcap=cfg.attn.logit_softcap, causal=cfg.attn.causal),
             remat=False)
     raise ValueError(scale)
+
+
+def model_config(arch: str, scale: str):
+    """The float32 :class:`ArchConfig` that ``main`` trains for
+    ``--arch``/``--scale``."""
+    base = (registry.get_smoke(arch) if scale == "smoke"
+            else registry.get(arch))
+    return scale_config(base, scale).with_overrides(param_dtype=jnp.float32)
 
 
 def make_algorithm(name, reg, tau, eta, eta_g):
@@ -127,7 +136,12 @@ def main_multiprocess(argv):
 
 
 def main(argv=None):
+    """Train per ``argv``; returns ``(state, snapshots)``: the final
+    algorithm state and the :class:`repro.serving.SnapshotStore` the run
+    published into (``None`` without ``--publish-snapshots``).  With
+    ``--processes`` it returns the runtime's exit code instead."""
     argv = sys.argv[1:] if argv is None else list(argv)
+    use_compile_cache()
     if any(s == "--processes" or s.startswith("--processes=")
            for s in argv):
         return main_multiprocess(argv)
@@ -252,10 +266,7 @@ def main(argv=None):
     sink = (obs_metrics.JsonlSink(args.metrics_jsonl)
             if args.metrics_jsonl else None)
 
-    base = (registry.get_smoke(args.arch) if args.scale == "smoke"
-            else registry.get(args.arch))
-    cfg = scale_config(base, args.scale).with_overrides(
-        param_dtype=jnp.float32)
+    cfg = model_config(args.arch, args.scale)
     params, _ = T.init_model(jax.random.PRNGKey(args.seed), cfg)
     n_params = T.count_params(params)
     print(f"arch={cfg.name} params={n_params:,} clients={args.clients} "
@@ -457,7 +468,7 @@ def main(argv=None):
         obs_trace.uninstall()
         print(f"trace -> {args.trace} ({tracer.n_spans} spans; open in "
               "Perfetto)")
-    return state
+    return state, snapshots
 
 
 if __name__ == "__main__":

@@ -24,7 +24,6 @@ for deepseek: 256 experts / 16, tokens / 16).
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Optional
 
 import jax
@@ -32,18 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.models.layers import MoECfg, gelu_mul, swiglu
-
-# jax.shard_map is top-level from 0.5.x; older versions ship it under
-# experimental with the replication check named check_rep instead of
-# check_vma.
-_shard_map = getattr(jax, "shard_map", None)
-if _shard_map is None:
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SM_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep"
-)
 
 
 def _local_moe_shard(x_loc, router, w_gate, w_up, w_down, *, cfg: MoECfg,
@@ -127,11 +114,11 @@ def moe_expert_parallel(p, cfg: MoECfg, x, mesh, *, act: str = "swiglu",
     body = functools.partial(_local_moe_shard, cfg=cfg, act=act, axis=axis,
                              n_shards=n_shards, token_axes=token_axes)
     tok_spec = P(token_axes if len(token_axes) > 1 else token_axes[0], None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok_spec, P(None, None), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None)),
         out_specs=(tok_spec, P()),
-        **{_SM_CHECK_KW: False},
+        check_vma=False,
     )
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])

@@ -136,21 +136,9 @@ class RooflineReport:
         )
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict across jax versions.
-
-    jax <= 0.4.x returns a one-element list of dicts; newer versions return
-    the dict directly.
-    """
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def analyze(compiled, *, arch: str, shape: str, mesh_name: str, n_chips: int,
             step_kind: str, model_flops: float, note: str = "") -> RooflineReport:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     txt = compiled.as_text()

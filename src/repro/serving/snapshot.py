@@ -29,6 +29,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Optional
 
 from repro.obs import trace as _trace
+from repro.utils.tree import tree_copy_shared
 
 
 @dataclass(frozen=True)
@@ -75,7 +76,8 @@ class SnapshotStore:
 
         ``value`` must not be mutated afterwards (the store does not
         copy -- publishing a device-resident pytree straight out of the
-        engine's committed state is the point).
+        engine's committed state is the point; :meth:`engine_sink` copies
+        only the arrays the engine is about to donate).
         """
         with self._cond:
             version = (self._current.version + 1) if self._current else 1
@@ -140,9 +142,14 @@ class SnapshotStore:
         it to the published value -- e.g. ``lambda s: global_params(reg,
         fcfg, s)`` for an LM, or ``None`` to publish the server-role
         fields dict the engine already extracted.
+
+        Arrays of ``value`` that are the engine's own carry arrays (FedAvg's
+        ``global_params`` is its ``state.x``) are copied on the device
+        first: on an accelerator the next chunk's call donates the carry,
+        and a snapshot sharing those buffers would be deleted with it.
         """
         def sink(end_round: int, state) -> None:
             value = state if select is None else select(state)
-            self.publish(value, round=end_round)
+            self.publish(tree_copy_shared(value, state), round=end_round)
 
         return sink

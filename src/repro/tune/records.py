@@ -45,16 +45,12 @@ def host_signature(x64: Optional[bool] = None) -> dict:
 
     import jax
 
-    try:
-        device_kind = jax.devices()[0].device_kind
-    except Exception:  # no devices visible (driver init failure)
-        device_kind = "unknown"
     if x64 is None:
         x64 = bool(jax.config.jax_enable_x64)
     return {
         "hostname": socket.gethostname(),
         "backend": jax.default_backend(),
-        "device_kind": device_kind,
+        "device_kind": jax.devices()[0].device_kind,
         "jax_version": jax.__version__,
         "x64": bool(x64),
     }

@@ -98,3 +98,12 @@ def tree_cast(a, dtype):
     return jax.tree_util.tree_map(
         lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x, a
     )
+
+
+def tree_copy_shared(a, b):
+    """``a`` with a fresh copy of every leaf that is also a leaf of ``b``
+    (the same array object), so that donating ``b``'s buffers leaves ``a``
+    intact, and the other way round."""
+    shared = {id(leaf) for leaf in jax.tree_util.tree_leaves(b)}
+    return jax.tree_util.tree_map(
+        lambda leaf: leaf.copy() if id(leaf) in shared else leaf, a)

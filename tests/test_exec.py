@@ -67,6 +67,32 @@ def _run_engine(engine, params0, supplier, rounds):
     return state, metrics
 
 
+@pytest.mark.parametrize("alg", ["dprox", "fedavg"])
+def test_donating_run_leaves_caller_params_intact(monkeypatch, alg):
+    """The algorithms keep ``params0`` itself as their first server state;
+    on an accelerator the compiled call donates the state.  ``init`` must
+    hand the engine copies, so the caller can start a second run (another
+    algorithm, another config) from the same ``params0``, bitwise."""
+    from repro.exec import engine as engine_mod
+
+    data, reg, grad_fn, params0 = _problem()
+    algorithm = (_dprox(reg) if alg == "dprox"
+                 else FedAvg(tau=3, eta=0.05))
+    sup = _supplier(data, 3, 8)
+    finals = []
+    for donating in (True, True, False):
+        monkeypatch.setattr(engine_mod, "donates", lambda: donating)
+        eng = RoundEngine(algorithm, grad_fn, data.n_clients,
+                          EngineConfig(chunk_rounds=2))
+        state, _ = _run_engine(eng, params0, sup, 4)
+        finals.append(b"".join(
+            np.asarray(leaf).tobytes()
+            for leaf in jax.tree_util.tree_leaves(eng.global_params(state))))
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(params0))
+    assert finals[0] == finals[1] == finals[2]
+
+
 # ---------------------------------------------------------------------------
 # chunked == unchunked
 # ---------------------------------------------------------------------------
@@ -133,12 +159,13 @@ def test_protocol_backend_matches_inline():
 
 
 def test_sharded_backend_matches_inline_single_device():
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     data, reg, grad_fn, params0 = _problem(seed=2)
     supplier = _supplier(data, 3, 8)
     alg = _dprox(reg)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     pspecs = {"w": ("mlp",), "b": ()}
     s_in, _ = _run_engine(
         RoundEngine(alg, grad_fn, data.n_clients,
@@ -168,11 +195,12 @@ def test_all_algorithms_sharded_match_inline(alg_factory):
     """state_roles + fed_state_shardings_from_roles place EVERY algorithm's
     federated state, not just DProxState -- trajectory parity for all
     seven."""
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     data, reg, grad_fn, params0 = _problem(seed=9)
     supplier = _supplier(data, 3, 8)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     pspecs = {"w": ("mlp",), "b": ()}
     alg = alg_factory(reg)
     e_in = RoundEngine(alg, grad_fn, data.n_clients,
@@ -201,7 +229,7 @@ from repro.core.prox import L1
 from repro.data.synthetic import logistic_heterogeneous, make_round_batches
 from repro.exec import EngineConfig, RoundEngine
 from repro.fed.simulator import DProxAlgorithm
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 from repro.models import logreg
 
 data = logistic_heterogeneous(n_clients=8, m_per_client=30, d=10,
@@ -218,7 +246,8 @@ sup = lambda r, rng: make_round_batches(data, 3, 8,
 inline = RoundEngine(alg, grad_fn, 8, EngineConfig(chunk_rounds=2))
 s_in, _ = inline.run(inline.init(params0), sup, 6, seed=0)
 
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 sharded = RoundEngine(alg, grad_fn, 8, EngineConfig(
     backend="sharded", chunk_rounds=2, mesh=mesh,
     param_specs={"w": ("mlp",), "b": ()}, plan="A"))

@@ -185,68 +185,3 @@ def test_spec_for_never_overassigns(seed):
             used.append(nm)
             sz *= FakeMesh.shape[nm]
         assert dim % sz == 0
-
-
-# ---------------------------------------------------------------------------
-# jax-compat shims stay the only callers of version-sensitive jax APIs
-# ---------------------------------------------------------------------------
-
-
-def test_version_sensitive_jax_apis_only_called_through_shims():
-    """Three jax APIs moved or changed shape across the versions this repo
-    supports, and each has exactly one compat shim:
-
-      * ``jax.shard_map``          -> ``repro.models.moe_ep._shard_map``
-      * ``jax.sharding.AxisType``  -> ``repro.launch.mesh.make_mesh_compat``
-      * ``compiled.cost_analysis()`` ->
-        ``repro.roofline.analysis.cost_analysis_dict``
-
-    A raw call anywhere else reintroduces the version skew the shims
-    exist to absorb, so this test greps the source tree for them.
-    Comments and docstrings are stripped line-wise (good enough: the
-    forbidden tokens never span lines).
-    """
-    import io
-    import pathlib
-    import re
-    import tokenize
-
-    src = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
-    shims = {src / "models" / "moe_ep.py",
-             src / "launch" / "mesh.py",
-             src / "roofline" / "analysis.py"}
-    patterns = {
-        "jax.shard_map": re.compile(r"\bjax\s*\.\s*shard_map\b"),
-        "jax.sharding.AxisType": re.compile(
-            r"\bjax\s*\.\s*sharding\s*\.\s*AxisType\b"),
-        ".cost_analysis()": re.compile(r"\.\s*cost_analysis\s*\("),
-    }
-    offenders = []
-    for path in sorted(src.rglob("*.py")):
-        if path in shims:
-            continue
-        text = path.read_text()
-        # drop comments + string literals so prose mentions don't trip it
-        code_lines = {}
-        try:
-            for tok in tokenize.generate_tokens(
-                    io.StringIO(text).readline):
-                if tok.type in (tokenize.COMMENT, tokenize.STRING):
-                    continue
-                if tok.type == tokenize.NAME or tok.type == tokenize.OP:
-                    code_lines.setdefault(tok.start[0], []).append(
-                        tok.string)
-        except tokenize.TokenError:
-            pytest.fail(f"could not tokenize {path}")
-        for lineno, toks in code_lines.items():
-            line = " ".join(toks)
-            for name, pat in patterns.items():
-                if pat.search(line):
-                    offenders.append(
-                        f"{path.relative_to(src.parent)}:{lineno} "
-                        f"calls {name} directly")
-    assert not offenders, (
-        "version-sensitive jax APIs must go through their compat shims "
-        "(repro.launch.mesh.make_mesh_compat, repro.models.moe_ep."
-        "_shard_map, repro.roofline.analysis.cost_analysis_dict):\n"
-        + "\n".join(offenders))

@@ -13,8 +13,8 @@ from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.models.layers import MoECfg, init_moe, moe
 from repro.models.moe_ep import moe_expert_parallel
 
-from repro.launch.mesh import make_mesh_compat
-mesh = make_mesh_compat((8,), ("model",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("model",), axis_types=(AxisType.Auto,))
 cfg = MoECfg(num_experts=8, top_k=2, d_ff_expert=32,
              capacity_factor=8.0 / 2 + 0.5)  # lossless
 d, T = 16, 64

@@ -214,10 +214,11 @@ def test_plane_engine_matches_per_leaf_bitwise(combo):
 
 def test_plane_engine_matches_per_leaf_placed():
     """Placement on top: flat carries get the 1-axis client placement."""
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     data, reg, grad_fn, params0 = _problem(seed=2)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     kw = dict(chunk_rounds=2, mesh=mesh,
               param_specs={"w": ("mlp",), "b": ()},
               transport=TopK(ratio=0.5),

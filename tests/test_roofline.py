@@ -19,9 +19,9 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.launch.mesh import make_mesh_compat
-from repro.roofline.analysis import cost_analysis_dict
-mesh = make_mesh_compat((4, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 B, S, H, D = 8, 256, 8, 64
 sh = NamedSharding(mesh, P("data", None, "model", None))
 
@@ -31,7 +31,7 @@ def f(q, k):
 c = jax.jit(f, in_shardings=(sh, sh)).lower(
     jax.ShapeDtypeStruct((B, S, H, D), jnp.float32),
     jax.ShapeDtypeStruct((B, S, H, D), jnp.float32)).compile()
-flops = cost_analysis_dict(c)["flops"]
+flops = c.cost_analysis()["flops"]
 analytic_per_dev = 2 * B * S * S * H * D / 8
 assert abs(flops / analytic_per_dev - 1) < 0.05, (flops, analytic_per_dev)
 
@@ -63,7 +63,7 @@ def cost(depth):
     c = jax.jit(stack(depth)).lower(
         jax.ShapeDtypeStruct((32, 128), jnp.float32),
         jax.ShapeDtypeStruct((depth, 128, 128), jnp.float32)).compile()
-    return cost_analysis_dict(c)["flops"]
+    return c.cost_analysis()["flops"]
 
 f2, f3 = cost(2), cost(3)
 C = f3 - f2

@@ -178,6 +178,51 @@ def test_worker_report_accounting():
     assert "train_loss" in rep["metrics"]
 
 
+def test_overlapped_sender_survives_carry_donation(monkeypatch):
+    """On an accelerator the engine donates its carry to the next chunk's
+    call while the sender thread still ships the last one: the sender
+    works from its own copy of the committed fields, so the pin holds."""
+    from repro.exec import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "donates", lambda: True)
+    box = _run_threaded(_base_args(mode="overlapped", rounds=16, chunk=2))
+    local = run_local(_base_args(mode="overlapped", rounds=16, chunk=2))
+    assert _fields_bitwise(local["fields"], box["server"]["fields"])
+
+
+def test_spawned_server_and_replicas_stay_off_the_accelerator(monkeypatch):
+    """Only workers may initialise the accelerator: the server and the
+    replicas do host work, so their processes are pinned to the CPU."""
+    from repro.fed import runtime
+
+    envs = {}
+
+    def fake_popen(argv, env):
+        envs[argv[argv.index("--role") + 1]] = env
+        return None
+
+    monkeypatch.setattr(runtime.subprocess, "Popen", fake_popen)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    a = _base_args()
+    for role in ("server", "replica", "worker"):
+        runtime._spawn(a, role, rank=1)
+    assert envs["server"]["JAX_PLATFORMS"] == "cpu"
+    assert envs["replica"]["JAX_PLATFORMS"] == "cpu"
+    assert "JAX_PLATFORMS" not in envs["worker"]
+
+
+def test_pair_refuses_a_second_tpu_worker(monkeypatch):
+    """libtpu admits one process per host, so a TPU backend runs exactly
+    one worker process; more fail before any process starts."""
+    from repro.fed import runtime
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(runtime, "_spawn", lambda *a, **k: pytest.fail(
+        "spawned a process"))
+    with pytest.raises(ValueError, match="one process per host"):
+        run_pair(_base_args(workers=2))
+
+
 @pytest.mark.slow
 def test_true_subprocess_pair_parity():
     """The real thing: one server OS process + one worker OS process

@@ -243,7 +243,7 @@ class TestDeltaReplica:
 # ---------------------------------------------------------------------------
 
 
-def _logreg_engine(config=None, n=6, seed=0):
+def _logreg_engine(config=None, n=6, seed=0, alg=None):
     from repro.core import algorithm as A
     from repro.core.prox import L1
     from repro.data.synthetic import logistic_heterogeneous
@@ -257,8 +257,8 @@ def _logreg_engine(config=None, n=6, seed=0):
     s = np.linalg.norm(data.features.reshape(-1, d), axis=1).max()
     data.features = (data.features / s).astype(np.float64)
     data.labels = data.labels.astype(np.float64)
-    alg = DProxAlgorithm(L1(lam=0.01),
-                         A.DProxConfig(tau=3, eta=0.05, eta_g=2.0))
+    alg = alg or DProxAlgorithm(L1(lam=0.01),
+                                A.DProxConfig(tau=3, eta=0.05, eta_g=2.0))
     eng = RoundEngine(alg, logreg.make_grad_fn(), data.n_clients,
                       config or EngineConfig(chunk_rounds=4))
     params0 = {"w": jnp.zeros(d, jnp.float64),
@@ -292,6 +292,32 @@ class TestEngineSnapshotSink:
         st2 = eng2.init(params0)
         st2, _ = eng2.run(st2, sup, 11, seed=0)
         assert _tree_bytes(st2.x_bar) == _tree_bytes(state.x_bar)
+
+    def test_snapshot_survives_carry_donation(self, monkeypatch):
+        """FedAvg's global model IS its carry (``state.x``).  On an
+        accelerator the next chunk's call donates that carry, so the sink
+        must publish a copy: snapshot v1, read after chunk 2, is intact and
+        bitwise the one a non-donating run publishes."""
+        from repro.core.baselines import FedAvg
+        from repro.exec import EngineConfig
+        from repro.exec import engine as engine_mod
+
+        def first_snapshot(donating: bool):
+            monkeypatch.setattr(engine_mod, "donates", lambda: donating)
+            store = SnapshotStore()
+            eng, params0, sup = _logreg_engine(
+                EngineConfig(chunk_rounds=2), alg=FedAvg(tau=3, eta=0.05))
+            eng.set_snapshot_sink(store.engine_sink(select=eng.global_params))
+            state0 = eng.init(params0)
+            eng.run(state0, sup, 4, seed=0)  # two chunks
+            # the donating build really donated: the initial carry is gone
+            assert all(leaf.is_deleted() == donating
+                       for leaf in jax.tree_util.tree_leaves(state0))
+            v1 = store.previous()
+            assert (v1.version, v1.round, store.version) == (1, 2, 2)
+            return _tree_bytes(v1.value)
+
+        assert first_snapshot(True) == first_snapshot(False)
 
     def test_protocol_blocked(self):
         from repro.exec import EngineConfig

@@ -88,11 +88,12 @@ def _assert_states_equal(a, b, exact=True):
 
 
 def test_placement_stage_matches_legacy_sharded_bitwise():
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     data, reg, grad_fn, params0 = _problem(seed=1)
     sup = ArraySupplier.from_dataset(data, 3, 8, seed=2)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     pspecs = {"w": ("mlp",), "b": ()}
     alg = _dprox(reg)
     _, s_new, m_new = _run(
@@ -169,9 +170,10 @@ def test_backend_alias_emits_deprecation_and_maps():
     with pytest.warns(DeprecationWarning):
         stack = EngineConfig(backend="protocol").resolve()
     assert stack.protocol and not stack.split
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     with pytest.warns(DeprecationWarning):
         stack = EngineConfig(backend="sharded", mesh=mesh,
                              param_specs={"w": ("mlp",)}).resolve()
@@ -284,12 +286,13 @@ def test_async_downlink_invariant_to_chunking():
 def test_full_stack_identity_points_match_inline():
     """Placement + UplinkComm + Asynchrony all active at their identity
     points reproduces the bare inline trajectory on the CPU mesh."""
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     data, reg, grad_fn, params0 = _problem(seed=9)
     sup = ArraySupplier.from_dataset(data, 3, 8, seed=10)
     alg = _dprox(reg)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     _, s_in, m_in = _run(alg, grad_fn, data.n_clients,
                          EngineConfig(chunk_rounds=2), params0, sup, 6)
     _, s_f, m_f = _run(
@@ -306,12 +309,13 @@ def test_full_stack_identity_points_match_inline():
 def test_full_stack_compressed_async_sharded_end_to_end():
     """mesh + transport + downlink + clock + queue, all non-trivial, in one
     compiled scan -- the composition the backend enum made impossible."""
-    from repro.launch.mesh import make_mesh_compat
+    from jax.sharding import AxisType
 
     data, reg, grad_fn, params0 = _problem(seed=10)
     sup = ArraySupplier.from_dataset(data, 3, 8, seed=11)
     alg = _dprox(reg)
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     eng, state, m = _run(
         alg, grad_fn, data.n_clients,
         EngineConfig(chunk_rounds=3, mesh=mesh,
@@ -349,7 +353,7 @@ from repro.core.prox import L1
 from repro.data.synthetic import logistic_heterogeneous
 from repro.exec import ArraySupplier, EngineConfig, RoundEngine
 from repro.fed.simulator import DProxAlgorithm
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 from repro.models import logreg
 from repro.sched import Staleness, StragglerClock
 
@@ -369,7 +373,8 @@ kw = dict(chunk_rounds=2, transport=TopK(ratio=0.5),
 bare = RoundEngine(alg, grad_fn, 8, EngineConfig(**kw))
 s_b, _ = bare.run(bare.init(params0), sup, 6, seed=0)
 
-mesh = make_mesh_compat((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 placed = RoundEngine(alg, grad_fn, 8, EngineConfig(
     mesh=mesh, param_specs={"w": ("mlp",), "b": ()}, plan="A", **kw))
 s_p, _ = placed.run(placed.init(params0), sup, 6, seed=0)
