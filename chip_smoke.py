@@ -12,9 +12,11 @@ back to the CPU.  Phases on one chip:
     ``repro.fed.simulator.run``.  Checks that the loss is finite and falls;
     that each round of the dense engine agrees with the literal per-client
     protocol of Algorithm 1 (``EngineConfig(protocol=True)``) from the same
-    state within ``TOL_PROTOCOL``; and that a flat-plane run with a global top-k uplink trains, with the
-    compiled threshold-select kernel it runs equal, bit for bit, to the
-    ``jnp.where`` select on the same plane.
+    state within ``TOL_PROTOCOL``; and that a flat-plane run with a global
+    top-k uplink trains, with the compiled threshold-select kernel it runs
+    equal, bit for bit, to the ``jnp.where`` select on the same plane.  The
+    quantize and commit kernels run on that plane (whose last block is
+    ragged) against ``repro.kernels.ref``.
   * the LM entry points: ``repro.launch.train.main`` on the stablelm smoke
     preset with ``--publish-snapshots``, then ``ServingEngine.serve``
     answering requests from the latest snapshot.
@@ -49,7 +51,7 @@ import numpy as np  # noqa: E402
 
 from repro.utils.compile_cache import CACHE_DIR, use_compile_cache  # noqa: E402
 
-N_CLIENTS, TAU, BATCH = 10, 5, 10
+SEED, N_CLIENTS, TAU, BATCH = 0, 10, 5, 10
 ROUNDS, CHUNK = 16, 8
 ETA, ETA_G, LAM = 0.005, 1.0, 1e-4  # benchmarks/fig4_cnn.py (paper Fig. 4)
 #: one round of the engine vs the per-client protocol from the same state,
@@ -107,17 +109,17 @@ def device_gate(count: int) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def cnn_problem(n_clients: int, seed: int):
+def cnn_problem(n_clients: int):
     """(params0, grad_fn, supplier, d) for the Section 4.2 CNN over
     ``n_clients`` label-skewed clients."""
     from repro.data.mnist_like import (generate, heterogeneous_split,
                                        sample_round_batches)
     from repro.models import cnn
 
-    tx, ty, sx, sy = generate(n_train=200 * n_clients, n_test=200, seed=seed)
+    tx, ty, sx, sy = generate(n_train=200 * n_clients, n_test=200, seed=SEED)
     data = heterogeneous_split(tx, ty, sx, sy, n_clients=n_clients,
-                               seed=seed)
-    p0 = cnn.init_params(jax.random.PRNGKey(seed))
+                               seed=SEED)
+    p0 = cnn.init_params(jax.random.PRNGKey(SEED))
     d = sum(int(np.size(l)) for l in jax.tree_util.tree_leaves(p0))
 
     def supplier(r, rng):
@@ -136,7 +138,7 @@ def rel_displacement_error(a, b, x0) -> float:
     return float(np.linalg.norm(a - b) / np.linalg.norm(b - x0))
 
 
-def cnn_phase(seed: int, clock: CompileClock) -> None:
+def cnn_phase(clock: CompileClock) -> None:
     from repro.comm import TopK
     from repro.core import plane as pln
     from repro.core.algorithm import DProxConfig
@@ -144,8 +146,9 @@ def cnn_phase(seed: int, clock: CompileClock) -> None:
     from repro.exec import EngineConfig, RoundEngine
     from repro.fed.simulator import DProxAlgorithm, run
     from repro.kernels import ops as kops
+    from repro.kernels import ref as kref
 
-    p0, grad_fn, supplier, d = cnn_problem(N_CLIENTS, seed)
+    p0, grad_fn, supplier, d = cnn_problem(N_CLIENTS)
     check(d == 112_394, f"CNN has d = {d}, the paper's is 112,394")
     alg = DProxAlgorithm(L1(lam=LAM), DProxConfig(tau=TAU, eta=ETA,
                                                   eta_g=ETA_G))
@@ -154,7 +157,7 @@ def cnn_phase(seed: int, clock: CompileClock) -> None:
         t0, c0 = time.perf_counter(), clock.seconds
         engine = RoundEngine(alg, grad_fn, N_CLIENTS, config)
         hist = run(alg, p0, grad_fn, supplier, N_CLIENTS, ROUNDS,
-                   engine=engine, seed=seed, eval_every=ROUNDS)
+                   engine=engine, seed=SEED, eval_every=ROUNDS)
         loss = np.asarray(hist.loss, np.float64)
         print(f"cnn/{label}: {time.perf_counter() - t0:.1f}s wall, "
               f"{clock.seconds - c0:.1f}s compiling; loss "
@@ -177,7 +180,7 @@ def cnn_phase(seed: int, clock: CompileClock) -> None:
     high = RoundEngine(alg, grad_fn, N_CLIENTS, EngineConfig())
     dflt = RoundEngine(alg, grad_fn, N_CLIENTS, EngineConfig())
     state = high.init(p0)
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     t0, c0 = time.perf_counter(), clock.seconds
     for r in range(ROUNDS):
         batches = supplier(r, rng)
@@ -218,7 +221,7 @@ def cnn_phase(seed: int, clock: CompileClock) -> None:
     ).compile().as_text()
     check("tpu_custom_call" in hlo,
           "global top-k did not lower to the threshold-select kernel")
-    flat = jax.random.normal(jax.random.PRNGKey(seed + 1),
+    flat = jax.random.normal(jax.random.PRNGKey(SEED + 1),
                              (N_CLIENTS, spec.d_pad), jnp.float32)
     flat = flat.at[:, spec.d:].set(0.0)
     k = max(1, round(topk.ratio * spec.d))
@@ -233,13 +236,48 @@ def cnn_phase(seed: int, clock: CompileClock) -> None:
     check(got.tobytes() == want.tobytes(),
           "threshold-select kernel differs from jnp.where")
 
+    # (d) the quantize and commit kernels on the same plane, whose tile rows
+    # leave a ragged last block, against repro.kernels.ref
+    levels = 255
+    u = jax.random.uniform(jax.random.PRNGKey(SEED + 2), flat.shape)
+    scale = jnp.max(jnp.abs(flat), axis=1)
+    got_q, want_q = (np.asarray(jax.jit(f, static_argnums=3)(
+        flat, u, scale, levels)) for f in (kops.plane_quantize,
+                                            kref.plane_quantize))
+    # an ulp of y = x / s * levels moves floor(y) where y sits on an
+    # integer: such a coordinate differs by one level, any other by rounding
+    err = np.abs(got_q - want_q)
+    step = np.asarray(scale, np.float64)[:, None] / levels
+    flips = int(np.count_nonzero(err > 1e-6))
+    print(f"cnn: quantize kernel vs ref ({levels} levels): {flips} of "
+          f"{err.size} coordinates a level apart, max error "
+          f"{float(np.max(err / step)):.4f} levels", flush=True)
+    check(bool(np.all(err <= step + 1e-6)) and flips <= err.size * 1e-4,
+          f"quantize kernel differs from ref: {flips} coordinates beyond "
+          f"1e-6, max {float(np.max(err / step)):.3f} levels")
+    w = jax.random.uniform(jax.random.PRNGKey(SEED + 3), (N_CLIENTS,))
+    got_c, want_c = (np.asarray(jax.jit(f)(flat, w)) for f in (
+        kops.plane_weighted_commit, kref.plane_weighted_commit))
+    terms = np.asarray(flat, np.float64) * np.asarray(w, np.float64)[:, None]
+    # the float32 summation bound: n * eps * sum_i |w_i x_i| per coordinate
+    bound = N_CLIENTS * 2.0 ** -23 * np.abs(terms).sum(axis=0)
+    exact = terms.sum(axis=0)
+    ratio = {name: float(np.max(np.abs(v - exact) / np.maximum(bound, 1e-30)))
+             for name, v in (("kernel", got_c), ("ref", want_c))}
+    print(f"cnn: commit kernel vs float64 sum: max error {ratio['kernel']:.3f}"
+          f" of the float32 summation bound (ref: {ratio['ref']:.3f}); "
+          f"kernel vs ref max |diff| {float(np.max(np.abs(got_c - want_c))):.3e}",
+          flush=True)
+    check(ratio["kernel"] <= 1.0, "commit kernel error exceeds the float32 "
+          f"summation bound ({ratio['kernel']:.3f} of it)")
+
 
 # ---------------------------------------------------------------------------
 # LM: train -> publish -> serve
 # ---------------------------------------------------------------------------
 
 
-def lm_phase(seed: int, clock: CompileClock) -> None:
+def lm_phase(clock: CompileClock) -> None:
     from repro.launch import train
     from repro.serving import Request, ServingEngine
 
@@ -251,7 +289,7 @@ def lm_phase(seed: int, clock: CompileClock) -> None:
         "--arch", "stablelm_1_6b", "--scale", "smoke", "--algorithm",
         "fedavg", "--rounds", str(rounds), "--chunk", str(chunk),
         "--clients", "2", "--tau", "2", "--batch", "2", "--seq", "64",
-        "--log-every", "1", "--seed", str(seed), "--publish-snapshots"])
+        "--log-every", "1", "--seed", str(SEED), "--publish-snapshots"])
     check(snapshots is not None and snapshots.version == rounds // chunk,
           f"expected {rounds // chunk} snapshots, store holds "
           f"{None if snapshots is None else snapshots.version}")
@@ -261,7 +299,7 @@ def lm_phase(seed: int, clock: CompileClock) -> None:
               f"snapshot v{snap.version} is not finite")
     cfg = train.model_config("stablelm_1_6b", "smoke")
     server = ServingEngine(cfg, None, max_len=96, snapshots=snapshots)
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     n_new = 8
     requests = [Request(i, rng.integers(0, cfg.vocab, size=16 + 8 * i,
                                         dtype=np.int32), n_new)
@@ -290,7 +328,7 @@ def lm_phase(seed: int, clock: CompileClock) -> None:
 # ---------------------------------------------------------------------------
 
 
-def placement_phase(seed: int, clock: CompileClock, n_devices: int) -> None:
+def placement_phase(clock: CompileClock, n_devices: int) -> None:
     from jax.sharding import AxisType
 
     from repro.comm import TopK
@@ -300,7 +338,7 @@ def placement_phase(seed: int, clock: CompileClock, n_devices: int) -> None:
     from repro.fed.simulator import DProxAlgorithm
 
     n_clients = 8
-    p0, grad_fn, supplier, _ = cnn_problem(n_clients, seed)
+    p0, grad_fn, supplier, _ = cnn_problem(n_clients)
     alg = DProxAlgorithm(L1(lam=LAM), DProxConfig(tau=TAU, eta=ETA,
                                                   eta_g=ETA_G))
     mesh = jax.make_mesh((n_devices, 1), ("data", "model"),
@@ -319,7 +357,7 @@ def placement_phase(seed: int, clock: CompileClock, n_devices: int) -> None:
                                       transport=transport))
     state = ref.init(p0)
     shardings = placed.state_shardings(state)
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     worst, losses = 0.0, []
     t0, c0 = time.perf_counter(), clock.seconds
     # round by round from the same state, so rounding differences (the
@@ -360,7 +398,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the client-axis placement phase")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     use_compile_cache()
     dev = device_gate(args.chips)
@@ -368,10 +405,10 @@ def main(argv=None) -> int:
     if args.chips == 4:
         check(dev["count"] == 4, f"--chips 4 needs exactly 4 devices, JAX "
               f"sees {dev['count']}")
-        placement_phase(args.seed, clock, 4)
+        placement_phase(clock, 4)
     else:
-        cnn_phase(args.seed, clock)
-        lm_phase(args.seed, clock)
+        cnn_phase(clock)
+        lm_phase(clock)
     cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CACHE_DIR)
     n_cached = len(os.listdir(cache)) if os.path.isdir(cache) else 0
     print(f"compile: {clock.seconds:.1f}s in XLA; persistent cache {cache} "

@@ -300,18 +300,19 @@ class _UplinkSender:
     # -- the engine-facing callback --------------------------------------
 
     def sink(self, start_round: int, msgs, state) -> None:
-        from repro.exec.engine import server_state_fields
+        from repro.exec import engine as engine_mod
         from repro.utils.tree import tree_copy_shared
 
         if self._err is not None:
             raise RuntimeError("uplink sender died") from self._err
+        committed = engine_mod.server_state_fields(self.algorithm, state)
+        if engine_mod.donates():
+            # copied here, on the compute thread and outside the wait span:
+            # the engine donates ``state`` to the next chunk's call, which
+            # deletes its buffers before the sender thread reads them
+            committed = tree_copy_shared(committed, state)
         with obs_trace.timed("uplink/wait", "uplink",
                              start_round=int(start_round)) as tm:
-            # copied here, on the compute thread: on an accelerator the
-            # engine donates ``state`` to the next chunk's call, which
-            # deletes its buffers before the sender thread reads them
-            committed = tree_copy_shared(
-                server_state_fields(self.algorithm, state), state)
             if self._q is None:
                 self._ship(start_round, msgs, committed)
             else:
@@ -871,10 +872,54 @@ def _free_port(host: str) -> int:
     return port
 
 
-def _spawn(a: RuntimeArgs, role: str, rank: int = 0) -> subprocess.Popen:
+def _tpu_chips() -> int:
+    """TPU chips on this host's PCI bus, counted without starting a JAX
+    backend (0 where JAX is held to other platforms)."""
+    from jax._src import hardware_utils
+
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def _backend_started() -> bool:
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def _chip_env(chip: int, port: int) -> dict:
+    """Environment that confines a process to TPU chip ``chip`` as a
+    one-chip slice of its own: libtpu then lets several processes of one
+    host hold a chip each, without ``ALLOW_MULTIPLE_LIBTPU_LOAD``."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
+
+
+def _worker_chip_envs(a: RuntimeArgs) -> Optional[list]:
+    """One :func:`_chip_env` per worker rank on a TPU host that runs more
+    than one worker, else None; refuses more workers than chips."""
+    if a.workers <= 1:
+        return None
+    chips = _tpu_chips()
+    if chips == 0:
+        return None
+    if a.workers > chips:
+        raise ValueError(f"--workers {a.workers} needs one TPU chip per "
+                         f"worker process, and this host has {chips}")
+    return [_chip_env(w, _free_port("127.0.0.1")) for w in range(a.workers)]
+
+
+def _spawn(a: RuntimeArgs, role: str, rank: int = 0,
+           chip_env: Optional[dict] = None) -> subprocess.Popen:
     argv = [sys.executable, "-m", "repro.fed.runtime",
             "--role", role, "--rank", str(rank)] + _to_argv(a)
     env = dict(os.environ)
+    env.update(chip_env or {})
     # the directory holding the ``repro`` package (``src/``)
     src = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -891,21 +936,26 @@ def run_pair(a: RuntimeArgs) -> dict:
     """Server subprocess + ``a.workers`` workers (rank 0 runs in this
     process so its report and exceptions surface directly).
 
-    On a TPU backend only this process may hold the chips: libtpu lets one
-    process per host load it (``/tmp/libtpu_lockfile``), so the run takes
-    exactly one worker there and refuses more."""
-    import jax
-
-    if a.workers > 1 and jax.default_backend() == "tpu":
-        raise ValueError(
-            f"--workers {a.workers} needs {a.workers} processes on the TPU, "
-            "but libtpu admits one process per host; run one worker on the "
-            "TPU, or the workers on the CPU with JAX_PLATFORMS=cpu")
+    libtpu lets one process of a host load it (``/tmp/libtpu_lockfile``)
+    unless each process is confined to chips of its own.  On a TPU host
+    with several workers, worker ``w`` gets chip ``w`` (:func:`_chip_env`),
+    this process chip 0 for good, before it first starts a backend; more
+    workers than chips are refused."""
+    envs = _worker_chip_envs(a)
+    if envs is not None and os.environ.get("TPU_VISIBLE_CHIPS") != "0":
+        if _backend_started():
+            raise RuntimeError(
+                f"--workers {a.workers} on the TPU: this process has started "
+                "JAX on every chip already; launch the workers before "
+                "anything runs on the device")
+        os.environ.update(envs[0])
     if a.port == 0:
         a.port = _free_port(a.host)
     procs = [_spawn(a, "server")]
     try:
-        procs += [_spawn(a, "worker", rank=w) for w in range(1, a.workers)]
+        procs += [_spawn(a, "worker", rank=w,
+                         chip_env=None if envs is None else envs[w])
+                  for w in range(1, a.workers)]
         procs += [_spawn(a, "replica", rank=r) for r in range(a.replicas)]
         rep = run_worker(a, rank=0)
         for p in procs:

@@ -143,13 +143,18 @@ class SnapshotStore:
         fcfg, s)`` for an LM, or ``None`` to publish the server-role
         fields dict the engine already extracted.
 
-        Arrays of ``value`` that are the engine's own carry arrays (FedAvg's
-        ``global_params`` is its ``state.x``) are copied on the device
-        first: on an accelerator the next chunk's call donates the carry,
-        and a snapshot sharing those buffers would be deleted with it.
+        Where the engine donates (on an accelerator), arrays of ``value``
+        that are the engine's own carry arrays (FedAvg's ``global_params``
+        is its ``state.x``) are copied on the device first: the next
+        chunk's call donates the carry, and a snapshot sharing those
+        buffers would be deleted with it.
         """
+        from repro.exec import engine as engine_mod
+
         def sink(end_round: int, state) -> None:
             value = state if select is None else select(state)
-            self.publish(tree_copy_shared(value, state), round=end_round)
+            if engine_mod.donates():
+                value = tree_copy_shared(value, state)
+            self.publish(value, round=end_round)
 
         return sink

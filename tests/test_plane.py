@@ -410,7 +410,8 @@ def test_plane_transport_compress_matches_pytree_compress():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("shape", [(3, 128), (5, 512), (2, 1024)])
+# (3, 640) has 5 tile rows: two 2-row blocks and a ragged last one
+@pytest.mark.parametrize("shape", [(3, 128), (5, 512), (2, 1024), (3, 640)])
 def test_threshold_select_kernel_matches_ref(shape):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -420,31 +421,53 @@ def test_threshold_select_kernel_matches_ref(shape):
                                   np.asarray(ref.plane_threshold_select(x, th)))
 
 
+# 640 lanes are 5 tile rows: block_rows=2 leaves a ragged last block
+@pytest.mark.parametrize("block_rows", [1, 2])
 @pytest.mark.parametrize("bits", [4, 8])
-def test_quantize_kernel_matches_ref(bits):
+def test_quantize_kernel_matches_ref(bits, block_rows):
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.normal(size=(4, 640)), jnp.float32)
     u = jnp.asarray(rng.uniform(size=(4, 640)), jnp.float32)
     s = jnp.max(jnp.abs(x), axis=1)
     levels = (1 << bits) - 1
-    got = ops.plane_quantize(x, u, s, levels, interpret=True, block_rows=1)
+    got = ops.plane_quantize(x, u, s, levels, interpret=True,
+                             block_rows=block_rows)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref.plane_quantize(x, u, s, levels)),
         atol=1e-6)
     # zero rows quantize to zero (scale guard)
     z = jnp.zeros((2, 256), jnp.float32)
     got = ops.plane_quantize(z, u[:2, :256], jnp.zeros(2), levels,
-                             interpret=True, block_rows=1)
+                             interpret=True, block_rows=block_rows)
     np.testing.assert_array_equal(np.asarray(got), 0.0)
 
 
-def test_weighted_commit_kernel_matches_ref():
+# (6, 640) has 5 tile rows: two 2-row blocks and a ragged last one
+@pytest.mark.parametrize("shape", [(6, 512), (6, 640)])
+def test_weighted_commit_kernel_matches_ref(shape):
     rng = np.random.default_rng(2)
-    buf = jnp.asarray(rng.normal(size=(6, 512)), jnp.float32)
-    w = jnp.asarray(rng.uniform(size=6), jnp.float32)
+    buf = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    w = jnp.asarray(rng.uniform(size=shape[0]), jnp.float32)
     got = ops.plane_weighted_commit(buf, w, interpret=True, block_rows=2)
     # the kernel accumulates sequentially in fp32; jnp.sum may reduce in a
     # different order -- 1-ulp tolerance
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(ref.plane_weighted_commit(buf, w)),
+        rtol=1e-5, atol=1e-6)
+
+
+def test_weighted_commit_block_shrinks_with_clients(monkeypatch):
+    """Many clients against a small VMEM budget: the commit block drops to
+    8 rows, and 20 tile rows leave a ragged last block."""
+    from repro.kernels import plane_ops
+
+    n, rows = 40, 20
+    monkeypatch.setattr(plane_ops, "COMMIT_VMEM_BYTES", n * 128 * 4 * 8)
+    assert plane_ops.commit_block_rows(n, rows, 4, plane_ops.BLOCK_ROWS) == 8
+    rng = np.random.default_rng(3)
+    buf = jnp.asarray(rng.normal(size=(n, rows * 128)), jnp.float32)
+    w = jnp.asarray(rng.uniform(size=n), jnp.float32)
+    got = ops.plane_weighted_commit(buf, w, interpret=True)
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(ref.plane_weighted_commit(buf, w)),
         rtol=1e-5, atol=1e-6)

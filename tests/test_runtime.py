@@ -211,16 +211,58 @@ def test_spawned_server_and_replicas_stay_off_the_accelerator(monkeypatch):
     assert "JAX_PLATFORMS" not in envs["worker"]
 
 
-def test_pair_refuses_a_second_tpu_worker(monkeypatch):
-    """libtpu admits one process per host, so a TPU backend runs exactly
-    one worker process; more fail before any process starts."""
+@pytest.mark.parametrize("why", ["more_workers_than_chips",
+                                 "backend_started"])
+def test_pair_refuses_tpu_workers_it_cannot_place(monkeypatch, why):
+    """On a TPU host each worker process needs a chip of its own, and this
+    process must be confined to chip 0 before it starts a backend: a run
+    that cannot have both fails before any process starts."""
     from repro.fed import runtime
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    monkeypatch.setattr(runtime, "_tpu_chips", lambda: 2)
+    monkeypatch.setattr(runtime, "_backend_started", lambda: True)
     monkeypatch.setattr(runtime, "_spawn", lambda *a, **k: pytest.fail(
         "spawned a process"))
-    with pytest.raises(ValueError, match="one process per host"):
-        run_pair(_base_args(workers=2))
+    workers, err = {"more_workers_than_chips": (3, ValueError),
+                    "backend_started": (2, RuntimeError)}[why]
+    with pytest.raises(err, match="chip"):
+        run_pair(_base_args(workers=workers))
+
+
+def test_pair_gives_each_tpu_worker_its_own_chip(monkeypatch):
+    """Worker ``w`` is confined to chip ``w`` (this process, rank 0, to
+    chip 0), each as a one-chip slice with a port of its own; the server
+    gets no chip."""
+    from repro.fed import runtime
+
+    for key in runtime._chip_env(0, 0):
+        monkeypatch.setenv(key, "")  # restored when the test ends
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS")
+    monkeypatch.setattr(runtime, "_tpu_chips", lambda: 4)
+    monkeypatch.setattr(runtime, "_backend_started", lambda: False)
+    spawned = {}
+
+    class _Done:
+        @staticmethod
+        def wait(timeout=None):
+            return 0
+
+        poll = wait
+
+    def fake_spawn(a, role, rank=0, chip_env=None):
+        spawned[role, rank] = chip_env
+        return _Done()
+
+    monkeypatch.setattr(runtime, "_spawn", fake_spawn)
+    monkeypatch.setattr(runtime, "run_worker", lambda a, rank: dict(
+        os.environ))
+    env0 = run_pair(_base_args(workers=3))
+    envs = [env0, spawned["worker", 1], spawned["worker", 2]]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2"]
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 3
+    assert spawned["server", 0] is None
 
 
 @pytest.mark.slow
