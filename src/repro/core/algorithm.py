@@ -31,6 +31,7 @@ Two equivalent implementations are provided:
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Callable, NamedTuple, Optional
 
@@ -44,6 +45,28 @@ Params = Any
 Batch = Any
 # grad_fn(params, batch) -> (loss, grads)
 GradFn = Callable[[Params, Batch], tuple[jax.Array, Params]]
+
+# Named scopes of the round's layers.  They reach each operation's HLO
+# metadata (``op_name``), so a profiler trace splits the device's time by
+# layer; they change nothing else.  The model's forward and backward pass
+# (GRAD) is nested in the client half (LOCAL); the engine puts the uplink's
+# work between the halves (compression, error feedback, client selection,
+# plane packing) under UPLINK (repro.exec.engine).
+LOCAL_SCOPE = "fl.local"
+GRAD_SCOPE = "fl.grad"
+UPLINK_SCOPE = "fl.uplink"
+SERVER_SCOPE = "fl.server"
+
+
+def _scoped(name: str, fn):
+    """``fn`` with every operation it traces under the named scope."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kw):
+        with jax.named_scope(name):
+            return fn(*args, **kw)
+
+    return scoped
 
 
 @dataclass(frozen=True)
@@ -176,7 +199,8 @@ def make_local_fn(
         def body(carry, t):
             z_hat, z, gsum, loss_sum = carry
             batch_t = jax.tree_util.tree_map(lambda x: x[:, t], batches)
-            losses, grads = jax.vmap(per_client_grad)(z, batch_t)  # (n,)
+            with jax.named_scope(GRAD_SCOPE):
+                losses, grads = jax.vmap(per_client_grad)(z, batch_t)  # (n,)
             # keep the federated state arithmetic in the params dtype (the
             # microbatched grad path accumulates in fp32)
             grads = jax.tree_util.tree_map(
@@ -217,7 +241,7 @@ def make_local_fn(
         }
         return msg, aux
 
-    return local_fn
+    return _scoped(LOCAL_SCOPE, local_fn)
 
 
 def make_server_fn(cfg: DProxConfig, reg: Regularizer):
@@ -292,7 +316,7 @@ def make_server_fn(cfg: DProxConfig, reg: Regularizer):
         )
         return new_state, metrics
 
-    return server_fn
+    return _scoped(SERVER_SCOPE, server_fn)
 
 
 def make_round_fn(

@@ -80,6 +80,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.algorithm import UPLINK_SCOPE
 from repro.core.baselines import FedAlgorithm
 from repro.obs import trace as _trace
 from repro.exec.stages import (Asynchrony, Cohort, DownlinkComm, Placement,
@@ -439,15 +440,15 @@ def _stack_batches(per_round: list) -> Batch:
     def lead1(x):
         return x[None] if isinstance(x, jax.Array) else np.asarray(x)[None]
 
-    if len(per_round) == 1:  # view, not copy -- the chunk-of-1 hot path
-        return jax.tree_util.tree_map(lead1, per_round[0])
-
     def stack(*xs):
         if any(isinstance(x, jax.Array) for x in xs):
             return jnp.stack([jnp.asarray(x) for x in xs])
         return np.stack([np.asarray(x) for x in xs])
 
-    return jax.tree_util.tree_map(stack, *per_round)
+    with _trace.span("exec/stack", "exec", rounds=len(per_round)):
+        if len(per_round) == 1:  # view, not copy -- the chunk-of-1 hot path
+            return jax.tree_util.tree_map(lead1, per_round[0])
+        return jax.tree_util.tree_map(stack, *per_round)
 
 
 class RoundEngine:
@@ -465,6 +466,7 @@ class RoundEngine:
         config: EngineConfig = EngineConfig(),
     ):
         stack = config.resolve()
+        _trace.watch_gc()
         self.algorithm = algorithm
         self.grad_fn = grad_fn
         self.n_clients = n_clients
@@ -543,6 +545,7 @@ class RoundEngine:
         self._plane = bool(config.plane) and stack.split
         self._plane_spec = None  # SegmentSpec of the uplink message plane
         self._chunked_call = None  # compiled lazily (needs a state template)
+        self._build_reason = "first"  # why the next build happens
         self._state_shardings = None
         self._extras = None  # dict of stage carry slices, built lazily
         self._donate_batches = False  # staged prefetch chunks (see run())
@@ -702,13 +705,15 @@ class RoundEngine:
                         st_v = st
                     b, a = xs if with_active else (xs, None)
                     msg, aux = local_fn(st_v, b)
-                    msg_hat, cs_new = transport.compress(cs, msg, sub)
+                    with jax.named_scope(UPLINK_SCOPE):
+                        msg_hat, cs_new = transport.compress(cs, msg, sub)
                     if with_active:
                         # inactive clients transmit nothing, so their
                         # error-feedback residuals must not advance -- else
                         # the telescoping identity (sent = produced - e_T)
                         # breaks per skipped round
-                        cs = transport.select_clients(a, cs_new, cs)
+                        with jax.named_scope(UPLINK_SCOPE):
+                            cs = transport.select_clients(a, cs_new, cs)
                         st, info = server_fn(st_v, msg_hat, aux, active=a)
                     else:
                         cs = cs_new
@@ -845,15 +850,19 @@ class RoundEngine:
 
         def local_eff(state, batches):
             msg, aux = local_fn(state, batches)
-            return pln.flatten(spec, msg), aux
+            with jax.named_scope(UPLINK_SCOPE):
+                return pln.flatten(spec, msg), aux
+
+        def unpack(flat):
+            with jax.named_scope(UPLINK_SCOPE):
+                return pln.unflatten(spec, flat)
 
         if self._accepts_active:
             def server_eff(state, flat, aux, active=None):
-                return server_fn(state, pln.unflatten(spec, flat), aux,
-                                 active=active)
+                return server_fn(state, unpack(flat), aux, active=active)
         else:
             def server_eff(state, flat, aux):
-                return server_fn(state, pln.unflatten(spec, flat), aux)
+                return server_fn(state, unpack(flat), aux)
 
         self._local_eff = local_eff
         self._server_eff = server_eff
@@ -898,7 +907,7 @@ class RoundEngine:
                     f"{', '.join(blockers)}; the per-chunk hand-off taps "
                     "the plain compiled scan")
         if (sink is None) != (self._uplink_sink is None):
-            self._chunked_call = None  # tap output is baked into the jit
+            self._invalidate("sink")  # tap output is baked into the jit
         self._uplink_sink = sink
         self._uplink_tap = None
 
@@ -952,8 +961,15 @@ class RoundEngine:
         if donate == self._donate_batches:
             return
         if self.config.jit and donates():
-            self._chunked_call = None
+            self._invalidate("donation")
         self._donate_batches = donate
+
+    def _invalidate(self, reason: str) -> None:
+        """Drop the compiled call: the next chunk rebuilds it, and its
+        ``exec/build`` span says why."""
+        if self._chunked_call is not None:
+            self._chunked_call = None
+            self._build_reason = reason
 
     def _invoke_stacked(self, state, batches, active):
         """Run one chunk of already-stacked batches through the compiled
@@ -968,7 +984,8 @@ class RoundEngine:
         if self._chunked_call is None:
             # NB the jit wrapper builds here but XLA compiles lazily: the
             # first exec/dispatch span carries trace + compile time
-            with _trace.span("exec/build", "exec"):
+            with _trace.span("exec/build", "exec",
+                             reason=self._build_reason):
                 self._chunked_call = self._build_chunked_call(state)
         if self.stack.split:
             with _trace.span("exec/dispatch", "exec"):
@@ -1123,10 +1140,13 @@ class RoundEngine:
                     "repro.exec.ArraySupplier")
             kw["client_ids"] = ids
         if use_stacked:
-            batches = supplier.sample_chunk(r0, c, rng, **kw)
+            with _trace.span("exec/supply", "exec"):
+                batches = supplier.sample_chunk(r0, c, rng, **kw)
         else:
-            batches = _stack_batches([
-                supplier.sample_round(r0 + i, rng, **kw) for i in range(c)])
+            with _trace.span("exec/supply", "exec"):
+                per_round = [supplier.sample_round(r0 + i, rng, **kw)
+                             for i in range(c)]
+            batches = _stack_batches(per_round)
         if self.stack.split and self._extras is None:
             # the stage carries must exist before the first swap registers
             # them (their init rows are the store's default rows)
@@ -1195,8 +1215,9 @@ class RoundEngine:
                         use_stacked)
                     self._fire_snapshot_sink(start_round + done + c, state)
                 elif use_stacked:
-                    batches = supplier.sample_chunk(start_round + done, c,
-                                                    rng)
+                    with _trace.span("exec/supply", "exec"):
+                        batches = supplier.sample_chunk(start_round + done,
+                                                        c, rng)
                     state, infos = self._invoke_stacked(state, batches, None)
                     # hand the chunk's uplink to the sink BEFORE the host
                     # sync: an overlapping sender starts fetching chunk k's
@@ -1213,28 +1234,32 @@ class RoundEngine:
                     # chunk-size-invariant rng stream: the trajectory must
                     # not depend on chunk_rounds
                     per_round, masks = [], []
-                    for i in range(c):
-                        per_round.append(supplier.sample_round(
-                            start_round + done + i, rng))
-                        if self._use_active:
-                            masks.append(sample_active_masks(
-                                self.n_clients, 1,
-                                self.config.participation, rng)[0])
+                    with _trace.span("exec/supply", "exec"):
+                        for i in range(c):
+                            per_round.append(supplier.sample_round(
+                                start_round + done + i, rng))
+                            if self._use_active:
+                                masks.append(sample_active_masks(
+                                    self.n_clients, 1,
+                                    self.config.participation, rng)[0])
                     active = np.stack(masks) if self._use_active else None
                     state, infos = self._invoke_chunk(state, per_round,
                                                       active)
                     self._fire_uplink_sink(start_round + done, state)
                     self._fire_snapshot_sink(start_round + done + c, state)
-            per_round_infos = [{} for _ in range(c)]
-            for k, v in infos.items():
-                arr = np.asarray(v)
-                for i in range(c):
-                    x = arr[i]
-                    per_round_infos[i][k] = float(x) if np.ndim(x) == 0 else x
-                    metrics.setdefault(k, []).append(per_round_infos[i][k])
-            if metrics_cb is not None:
-                for i in range(c):
-                    metrics_cb(start_round + done + i, per_round_infos[i])
+                per_round_infos = [{} for _ in range(c)]
+                for k, v in infos.items():
+                    arr = np.asarray(v)
+                    for i in range(c):
+                        x = arr[i]
+                        per_round_infos[i][k] = (float(x) if np.ndim(x) == 0
+                                                 else x)
+                        metrics.setdefault(k, []).append(
+                            per_round_infos[i][k])
+                if metrics_cb is not None:
+                    for i in range(c):
+                        metrics_cb(start_round + done + i,
+                                   per_round_infos[i])
             done += c
         if self._cohort is not None:
             self._cohort_round = start_round + rounds

@@ -97,4 +97,5 @@ def flash_attention(q, k, v, *, causal=True, window=None, softcap=None,
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

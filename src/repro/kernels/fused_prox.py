@@ -62,4 +62,5 @@ def fused_local_update_2d(z_hat, grads, c, eta, thresh, *, interpret=False,
         out_specs=[spec, spec],
         out_shape=out_shape,
         interpret=interpret,
+        name="fused_prox_update",
     )(scalars, z_hat, grads, c)

@@ -71,6 +71,7 @@ def threshold_select_3d(x, thresh, *, interpret=False,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="threshold_select",
     )(thresh.astype(jnp.float32), x)
 
 
@@ -104,6 +105,7 @@ def quantize_3d(x, u, scale, levels: int, *, interpret=False,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="quantize_plane",
     )(scale.astype(jnp.float32), x, u)
 
 
@@ -149,4 +151,5 @@ def weighted_commit_3d(buf, w, *, interpret=False, block_rows=None):
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((rows, LANES), buf.dtype),
         interpret=interpret,
+        name="weighted_commit",
     )(w.astype(jnp.float32), buf)

@@ -8,11 +8,18 @@ commits share a common timebase.
 
 Design constraints, in order:
 
-  * **disabled is free** -- the default tracer is :data:`NULL_TRACER`, whose
-    ``span()`` returns one shared no-op context manager: no clock read, no
-    allocation, no lock.  Instrumentation sites therefore stay in hot paths
-    permanently, and tests/test_obs.py pins the disabled path BITWISE
-    against an uninstrumented run;
+  * **disabled is nearly free** -- the default tracer is
+    :data:`NULL_TRACER`, whose ``span()`` costs one check that JAX's
+    profiler is off and then returns one shared no-op context manager: no
+    clock read, no allocation, no lock.  Instrumentation sites therefore
+    stay in hot paths permanently, and tests/test_obs.py pins the disabled
+    path BITWISE against an uninstrumented run;
+  * **on the profiler's clock** -- while a ``jax.profiler`` session
+    records, every :func:`span` and :func:`timed` (under either tracer)
+    also writes a TraceMe of the same name, with its args, into the
+    profiler's host plane, where the device's operations share its clock.
+    The session is the only switch.  :func:`watch_gc` adds a ``host/gc``
+    span per garbage-collector pause in the same way;
   * **low overhead when on** -- spans land in a preallocated numpy ring
     buffer (two float64 clock columns + three int32 index columns); names
     and categories are interned once; the only per-span lock is around the
@@ -27,8 +34,10 @@ Design constraints, in order:
     trace metadata rows name both, so the sender thread, the supplier
     staging thread and the compute thread render as separate tracks.
 
-No jax imports anywhere in this module: :mod:`repro.comm.wire` (numpy-only
-by contract) instruments through it.
+No jax import at module level: :mod:`repro.comm.wire` (numpy-only by
+contract) instruments through it.  The profiler's TraceMe is resolved
+lazily, and only once ``jax`` is imported: a process without jax cannot be
+profiling.
 
 Usage::
 
@@ -44,8 +53,10 @@ Usage::
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Optional
@@ -54,12 +65,86 @@ import numpy as np
 
 __all__ = ["now", "Tracer", "NullTracer", "NULL_TRACER", "install",
            "uninstall", "get", "span", "instant", "timed", "clock_offset",
-           "to_chrome", "write_chrome", "merge_wire", "validate_chrome"]
+           "recording", "watch_gc", "to_chrome", "write_chrome",
+           "merge_wire", "validate_chrome"]
 
 #: THE tracer clock: monotonic, high-resolution, per-process epoch.
 now = time.perf_counter
 
 SCHEMA = "repro.obs.trace/v1"
+
+
+# ---------------------------------------------------------------------------
+# the profiler bridge: spans on jax.profiler's clock
+# ---------------------------------------------------------------------------
+
+_PROFILER_SPAN: Any = None  # TraceMe subclass, built once jax is imported
+
+
+def _profiler_span_class():
+    global _PROFILER_SPAN
+    import jax.profiler
+
+    class ProfilerSpan(jax.profiler.TraceAnnotation):
+        """A TraceMe with the ``.set(**kw)`` of a tracer span."""
+
+        __slots__ = ()
+
+        def set(self, **kw) -> None:
+            self.set_metadata(**kw)
+
+    _PROFILER_SPAN = ProfilerSpan
+    return ProfilerSpan
+
+
+def recording():
+    """The profiler's span class while a ``jax.profiler`` session records,
+    else ``None``: one ``is_enabled()`` check once jax is imported, one
+    ``sys.modules`` lookup before."""
+    cls = _PROFILER_SPAN
+    if cls is None:
+        if "jax" not in sys.modules:
+            return None
+        cls = _profiler_span_class()
+    return cls if cls.is_enabled() else None
+
+
+def _profiler_enter(name: str, args):
+    """A profiler span named ``name``, entered, while a session records;
+    else None."""
+    cls = recording()
+    if cls is None:
+        return None
+    sp = cls(name, **(args or {}))
+    sp.__enter__()
+    return sp
+
+
+_GC_SPAN: Any = None  # the profiler span of the collection in progress
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _GC_SPAN
+    if phase == "start":
+        # no import from inside a collection: watch_gc resolved the class
+        cls = _PROFILER_SPAN
+        if cls is not None and cls.is_enabled():
+            _GC_SPAN = cls("host/gc", generation=info["generation"])
+            _GC_SPAN.__enter__()
+    elif _GC_SPAN is not None:
+        sp, _GC_SPAN = _GC_SPAN, None
+        sp.set(collected=info["collected"])
+        sp.__exit__(None, None, None)
+
+
+def watch_gc() -> None:
+    """Write a ``host/gc`` profiler span around every pause of Python's
+    garbage collector while a ``jax.profiler`` session records (CPython
+    runs one collection at a time).  Idempotent; call it once jax is
+    imported."""
+    recording()
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 # ---------------------------------------------------------------------------
@@ -86,13 +171,15 @@ _NULL_SPAN = _NullSpan()
 
 
 class NullTracer:
-    """The disabled tracer: every operation is a no-op."""
+    """The disabled tracer: every operation is a no-op, except that a span
+    reaches a recording ``jax.profiler`` session."""
 
     enabled = False
     process = "off"
 
     def span(self, name: str, cat: str = "", **args):
-        return _NULL_SPAN
+        cls = recording()
+        return _NULL_SPAN if cls is None else cls(name, **args)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         pass
@@ -110,9 +197,10 @@ NULL_TRACER = NullTracer()
 
 
 class _Span:
-    """One in-flight span; records (t0, t1) into the tracer on exit."""
+    """One in-flight span; records (t0, t1) into the tracer on exit, and
+    into a recording ``jax.profiler`` session."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_prof")
 
     def __init__(self, tr, name, cat, args):
         self._tr = tr
@@ -121,11 +209,14 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._prof = _profiler_enter(self._name, self._args)
         self._t0 = now()
         return self
 
     def __exit__(self, *exc):
         self._tr._record(self._name, self._cat, self._t0, now(), self._args)
+        if self._prof is not None:
+            self._prof.__exit__(*exc)
         return False
 
     def set(self, **kw) -> None:
@@ -135,6 +226,8 @@ class _Span:
             self._args = kw
         else:
             self._args.update(kw)
+        if self._prof is not None:
+            self._prof.set(**kw)
 
 
 class Tracer:
@@ -310,6 +403,7 @@ class timed:
         self.seconds = 0.0
 
     def __enter__(self):
+        self._prof = _profiler_enter(self.name, self.args)
         self.t0 = now()
         return self
 
@@ -319,6 +413,8 @@ class timed:
         tr = _TRACER
         if tr.enabled:
             tr._record(self.name, self.cat, self.t0, t1, self.args)
+        if self._prof is not None:
+            self._prof.__exit__(*exc)
         return False
 
 

@@ -517,3 +517,144 @@ def test_engine_trajectory_same_via_chunk_supplier(device_cache):
                                    rtol=1e-12, atol=1e-14)
         np.testing.assert_allclose(m_ref["train_loss"], m_sup["train_loss"],
                                    rtol=1e-6)
+
+
+# -- the engine's host spans and the round's layer scopes ---------------------
+
+def _spans(bundle) -> list:
+    """``[(name, t0, t1, args)]`` of a tracer's wire bundle."""
+    import json
+
+    names, args = bundle["names"], json.loads(bundle["args_json"])
+    return [(names[int(i)], float(a), float(b), ar) for i, a, b, ar in
+            zip(bundle["name_ix"], bundle["t0"], bundle["t1"], args)]
+
+
+@pytest.mark.parametrize("stacked", [False, True], ids=["per_round", "chunk"])
+def test_chunk_span_holds_the_engine_host_work(stacked):
+    """Every supplier call, the host stack, the dispatch, the host sync and
+    the per-round metrics callback of a chunk lie inside its exec/chunk."""
+    from repro.exec import ArraySupplier
+    from repro.obs import trace as obs_trace
+
+    data, reg, grad_fn, params0 = _problem(seed=3)
+    sup = ArraySupplier.from_dataset(data, 3, 8, seed=7)
+    engine = RoundEngine(_dprox(reg), grad_fn, data.n_clients,
+                         EngineConfig(chunk_rounds=2))
+    cb_times = []
+    tracer = obs_trace.install("test")
+    try:
+        engine.run(engine.init(params0),
+                   sup if stacked else (lambda r, rng: sup.sample_round(r)),
+                   4, seed=0,
+                   metrics_cb=lambda r, m: cb_times.append(obs_trace.now()))
+    finally:
+        obs_trace.uninstall()
+    spans = _spans(tracer.export_wire())
+    chunks = [(a, b) for n, a, b, _ in spans if n == "exec/chunk"]
+    assert len(chunks) == 2
+    inner = ["exec/supply", "exec/dispatch", "exec/host_sync"]
+    if not stacked:
+        inner.append("exec/stack")
+    for name in inner:
+        found = [(a, b) for n, a, b, _ in spans if n == name]
+        assert len(found) == 2, name
+        for (a, b), (c0, c1) in zip(found, chunks):
+            assert c0 <= a <= b <= c1, name
+    assert len(cb_times) == 4
+    for i, t in enumerate(cb_times):
+        c0, c1 = chunks[i // 2]
+        assert c0 <= t <= c1
+    builds = [ar for n, _, _, ar in spans if n == "exec/build"]
+    assert builds == [{"reason": "first"}]
+
+
+def test_build_span_names_why_it_rebuilt():
+    """exec/build says why the compiled call was (re)built: the first call,
+    or a sink change that alters the compiled chunk's outputs."""
+    from repro.comm import Dense
+    from repro.obs import trace as obs_trace
+
+    data, reg, grad_fn, params0 = _problem(seed=4)
+    engine = RoundEngine(_dprox(reg), grad_fn, data.n_clients,
+                         EngineConfig(chunk_rounds=2, transport=Dense()))
+    supplier = _supplier(data, 3, 8)
+    tracer = obs_trace.install("test")
+    try:
+        state = engine.init(params0)
+        state, _ = engine.run(state, supplier, 2, seed=0)
+        engine.set_uplink_sink(lambda r, msgs, st: None)
+        engine.run(state, supplier, 2, seed=0, start_round=2)
+    finally:
+        obs_trace.uninstall()
+    builds = [ar for n, _, _, ar in _spans(tracer.export_wire())
+              if n == "exec/build"]
+    assert builds == [{"reason": "first"}, {"reason": "sink"}]
+
+
+#: the four layers' scopes, and how they may nest (the gradient inside the
+#: client half)
+LAYERS = {("fl.local",): "local", ("fl.local", "fl.grad"): "grad",
+          ("fl.uplink",): "uplink", ("fl.server",): "server"}
+#: what the chunk's scan body does outside the round, by the tail of its
+#: op_name: the round's slice of the chunk's batches, the stacking of its
+#: metrics, the loop counter, and broadcasts of constants XLA names by the
+#: round's call
+PLUMBING = ("dynamic_slice", "dynamic_update_slice", "add", "closed_call")
+
+
+def _layer_of_instructions(hlo_text: str) -> dict:
+    """``{layer or 'plumbing: <tail>': count}`` over the instructions of the
+    chunk's scan body (op_name under ``jit(...)/while/body/``), by the set
+    of ``fl.*`` scope segments of their op_name.  Parameters and constants
+    run nothing and are left out."""
+    import re
+    from collections import Counter
+
+    out = Counter()
+    for line in hlo_text.splitlines():
+        m = re.match(r'\s*(?:ROOT )?%[\w.\-]+ = .*? ([\w\-]+)\(.*'
+                     r'op_name="(jit\(\w+\)/while/body/[^"]*)"', line)
+        if not m or m.group(1) in ("parameter", "constant"):
+            continue
+        tokens = tuple(dict.fromkeys(re.findall(r"\bfl\.\w+", m.group(2))))
+        if tokens:
+            out[LAYERS.get(tokens, f"mixed: {tokens}")] += 1
+        else:
+            out[f"plumbing: {m.group(2).rsplit('/', 1)[-1]}"] += 1
+    return out
+
+
+@pytest.mark.parametrize("uplink", [False, True], ids=["fused", "plane_topk"])
+def test_round_layers_carry_their_scopes(uplink):
+    """Every instruction of a DProx CNN round carries the scope of exactly
+    one layer (the gradient nested in the client half), on the fused path
+    and on the plane + global top-k path; what carries none is the chunk
+    scan's own plumbing."""
+    from repro.comm import TopK
+    from repro.exec.engine import _stack_batches
+    from repro.models import cnn
+
+    alg = DProxAlgorithm(L1(lam=1e-4), A.DProxConfig(tau=2, eta=0.005,
+                                                     eta_g=1.5))
+    kw = (dict(plane=True, transport=TopK(ratio=0.1, granularity="global"))
+          if uplink else {})
+    engine = RoundEngine(alg, cnn.make_grad_fn(), 2,
+                         EngineConfig(chunk_rounds=2, **kw))
+    rng = np.random.default_rng(0)
+
+    def supplier(r, rng):
+        return {"x": rng.standard_normal((2, 2, 2, 28, 28, 1), np.float32),
+                "y": rng.integers(0, 10, (2, 2, 2)).astype(np.int32)}
+
+    state = engine.init(cnn.init_params(jax.random.PRNGKey(0)))
+    state, _ = engine.run(state, supplier, 2, rng=rng)
+    carry = (state, engine._extras) if uplink else state
+    batches = _stack_batches([supplier(0, rng), supplier(1, rng)])
+    text = engine._chunked_call.lower(carry, batches, None).compile().as_text()
+    layers = _layer_of_instructions(text)
+    assert not [k for k in layers if k.startswith("mixed")], layers
+    assert {k for k in layers if k.startswith("plumbing")} <= {
+        f"plumbing: {t}" for t in PLUMBING}, layers
+    assert {"local", "grad", "server"} <= set(layers), layers
+    assert ("uplink" in layers) == uplink, layers

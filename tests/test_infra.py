@@ -185,3 +185,40 @@ def test_spec_for_never_overassigns(seed):
             used.append(nm)
             sz *= FakeMesh.shape[nm]
         assert dim % sz == 0
+
+
+def test_compile_cache_keys_on_named_scopes(monkeypatch, tmp_path):
+    """The entry points' compile cache keys each program on its metadata
+    too: two builds that differ only by a named scope (the layer a
+    profiler trace reports) do not share a cached executable."""
+    from jax._src import cache_key, compiler
+
+    from repro.utils.compile_cache import use_compile_cache
+
+    def scoped(name):
+        def f(x):
+            with jax.named_scope(name):
+                return jnp.sin(x) * 2.0
+
+        return jax.jit(f).lower(jnp.ones(4)).compiler_ir()
+
+    def key():
+        devices = np.array(jax.devices()[:1])
+        options = compiler.get_compile_options(1, 1)
+        return [cache_key.get(scoped(n), devices, options,
+                              jax.devices()[0].client) for n in ("a", "b")]
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_compilation_cache_include_metadata_in_key
+    try:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        a, b = key()
+        assert a == b
+        use_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        a, b = key()
+        assert a != b
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          was)

@@ -151,3 +151,36 @@ def test_gqa_wrapper_matches_model_attention():
     mask = L.causal_mask(s, s)[None, None]
     exp = L._sdpa(q, k, v, mask, 1.0 / (d ** 0.5))
     np.testing.assert_allclose(np.asarray(got), np.asarray(exp), atol=3e-5)
+
+
+# ---------------------------------------------------------------------------
+# kernel names: what a profiler trace and its readers find them by
+# ---------------------------------------------------------------------------
+
+
+def _kernel_calls():
+    from repro.kernels import flash_attention, fused_prox, plane_ops
+
+    x, t = jnp.ones((2, 8, 128)), jnp.ones((2,))
+    tile = jnp.ones((256, 128))
+    qkv = jnp.ones((1, 1, 128, 64))
+    return {
+        "threshold_select": (plane_ops.threshold_select_3d, (x, t), {}),
+        "quantize_plane": (plane_ops.quantize_3d, (x, x, t, 4), {}),
+        "weighted_commit": (plane_ops.weighted_commit_3d, (x, t), {}),
+        "fused_prox_update": (fused_prox.fused_local_update_2d,
+                              (tile, tile, tile, 0.1, 0.01), {}),
+        "flash_attention": (flash_attention.flash_attention, (qkv,) * 3,
+                            {"bq": 64, "bk": 64}),
+    }
+
+
+@pytest.mark.parametrize("name", ["threshold_select", "quantize_plane",
+                                  "weighted_commit", "fused_prox_update",
+                                  "flash_attention"])
+def test_pallas_call_carries_its_name(name):
+    """Each ``pallas_call`` is named: lowered in interpret mode, its body
+    sits under a scope of that name."""
+    fn, args, kw = _kernel_calls()[name]
+    text = fn.lower(*args, interpret=True, **kw).as_text(debug_info=True)
+    assert f"/{name}/" in text

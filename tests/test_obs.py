@@ -8,8 +8,11 @@ one without.  The merge tests pin what the CI smoke job's validator
 checks on a real 2-process trace: schema-valid events and proper span
 nesting per (pid, tid) track after clock-offset alignment.
 """
+import gc
+import glob
 import json
 import os
+import subprocess
 import sys
 
 import numpy as np
@@ -66,6 +69,102 @@ class TestTracerBitwise:
             pass
         assert tm.seconds >= 0.0
         assert isinstance(obs_trace.get(), obs_trace.NullTracer)
+
+
+def _host_events(logdir) -> dict:
+    """``{name: [(line name, stats dict)]}`` of the host plane of the one
+    xplane under ``logdir``."""
+    path, = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    out: dict = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    out.setdefault(e.name, []).append(
+                        (line.name, dict(e.stats)))
+    return out
+
+
+class TestProfilerBridge:
+    """While a ``jax.profiler`` session records, the tracer's spans also
+    land in the profiler's host plane, on the device trace's clock."""
+
+    def test_spans_reach_the_xplane(self, tmp_path):
+        f = jax.jit(lambda x: x * 2)
+        x = jax.numpy.ones(4)
+        f(x).block_until_ready()
+        ring = obs_trace.Tracer("ring")
+        with jax.profiler.trace(str(tmp_path)):
+            with obs_trace.span("exec/null_span", "exec", start_round=3):
+                f(x).block_until_ready()
+            with ring.span("exec/ring_span", "exec"):
+                f(x).block_until_ready()
+            with obs_trace.timed("exec/timed_span", "exec"):
+                pass
+        ev = _host_events(tmp_path)
+        for name in ("exec/null_span", "exec/ring_span", "exec/timed_span"):
+            assert [line for line, _ in ev[name]] == ["python"], name
+        assert ev["exec/null_span"][0][1] == {"start_round": 3}
+        assert ring.n_spans == 1  # the ring records as before
+
+    def test_null_span_shared_while_profiler_off(self):
+        assert obs_trace.recording() is None
+        assert obs_trace.span("a") is obs_trace._NULL_SPAN
+        with obs_trace.timed("t") as tm:
+            pass
+        assert tm._prof is None
+
+    def test_set_on_both_paths(self, tmp_path):
+        ring = obs_trace.Tracer("ring")
+        with jax.profiler.trace(str(tmp_path)):
+            with obs_trace.span("wire/null_set", "wire") as sp:
+                sp.set(nbytes=5)
+            with ring.span("wire/ring_set", "wire") as sp:
+                sp.set(nbytes=7)
+        ev = _host_events(tmp_path)
+        assert ev["wire/null_set"][0][1] == {"nbytes": 5}
+        assert ev["wire/ring_set"][0][1] == {"nbytes": 7}
+        assert json.loads(ring.export_wire()["args_json"]) == [{"nbytes": 7}]
+        obs_trace.span("wire/off").set(nbytes=1)  # profiler off: no-op
+        with ring.span("wire/off_ring") as sp:
+            sp.set(nbytes=2)
+        assert json.loads(ring.export_wire()["args_json"])[-1] == {
+            "nbytes": 2}
+
+    def test_gc_pauses_are_spans(self, tmp_path):
+        obs_trace.watch_gc()
+        obs_trace.watch_gc()
+        assert gc.callbacks.count(obs_trace._on_gc) == 1
+        gc.collect()  # profiler off: nothing opened
+        assert obs_trace._GC_SPAN is None
+        with jax.profiler.trace(str(tmp_path)):
+            gc.collect()
+        stats = [s for _, s in _host_events(tmp_path)["host/gc"]]
+        assert any(s["generation"] == 2 and "collected" in s for s in stats)
+
+    def test_wire_module_imports_without_jax(self):
+        """The wire codec's module and the tracer it imports load in a
+        process in which jax cannot be imported."""
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        code = (
+            "import importlib.util, sys\n"
+            "class NoJax:\n"
+            "    def find_spec(self, name, path=None, target=None):\n"
+            "        if name.split('.')[0] in ('jax', 'jaxlib'):\n"
+            "            raise ImportError('jax is not importable here')\n"
+            "sys.meta_path.insert(0, NoJax())\n"
+            f"path = {os.path.join(src, 'repro', 'comm', 'wire.py')!r}\n"
+            "spec = importlib.util.spec_from_file_location('wire', path)\n"
+            "wire = importlib.util.module_from_spec(spec)\n"
+            "spec.loader.exec_module(wire)\n"
+            "from repro.obs import trace\n"
+            "with trace.span('wire/encode') as sp:\n"
+            "    sp.set(nbytes=1)\n"
+            "assert trace.recording() is None\n"
+            "assert 'jax' not in sys.modules\n")
+        env = dict(os.environ, PYTHONPATH=src)
+        subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                       timeout=60)
 
 
 class TestChromeExport:

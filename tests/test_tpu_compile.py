@@ -136,3 +136,40 @@ def test_cnn_dprox_round_with_fused_kernel_compiles(one_chip, monkeypatch):
                              L1(lam=1e-4), cnn.make_grad_fn(),
                              use_fused_kernel=True)
     assert "tpu_custom_call" in _compiled_text(round_fn, state, batches)
+
+
+def test_cnn_dprox_chunk_ops_carry_layer_scopes(one_chip):
+    """Compiled for the chip, the operations of a chunk of CNN rounds keep
+    their layer's scope in their ``op_name`` (what a profiler trace of the
+    chip reports with each op): every op of the round that has one names
+    the gradient, the client half or the server half."""
+    import re
+
+    from repro.core.algorithm import DProxConfig, init_state, make_round_fn
+    from repro.core.prox import L1
+    from repro.models import cnn
+
+    n, tau, b = 10, 5, 10
+    params = jax.eval_shape(cnn.init_params, jax.random.PRNGKey(0))
+    state = jax.tree_util.tree_map(
+        lambda s: _shape(one_chip, s.shape, s.dtype),
+        jax.eval_shape(lambda p: init_state(p, n), params))
+    batches = {"x": _shape(one_chip, (2, n, tau, b, 28, 28, 1)),
+               "y": _shape(one_chip, (2, n, tau, b), jnp.int32)}
+    round_fn = make_round_fn(DProxConfig(tau=tau, eta=0.005, eta_g=1.0),
+                             L1(lam=1e-4), cnn.make_grad_fn())
+
+    def chunk(st, bs):
+        return jax.lax.scan(lambda s, x: round_fn(s, x), st, bs)
+
+    txt = _compiled_text(chunk, state, batches)
+    layers, bare = set(), []
+    for m in re.finditer(r'op_name="jit\(chunk\)/while/body/closed_call'
+                         r'(/[^"]*)?"', txt):
+        found = re.findall(r"\bfl\.\w+", m.group(1) or "")
+        if found:
+            layers.add(found[-1])
+        elif m.group(1):
+            bare.append(m.group(1))
+    assert layers == {"fl.grad", "fl.local", "fl.server"}
+    assert bare == []
