@@ -235,6 +235,19 @@ def cnn_phase(clock: CompileClock) -> None:
           flush=True)
     check(got.tobytes() == want.tobytes(),
           "threshold-select kernel differs from jnp.where")
+    # the k-th magnitude the kernel finds by bisection, against the sort's,
+    # on the same plane and on one whose first row holds a NaN
+    top_kth = jax.jit(lambda f: jax.lax.top_k(jnp.abs(f), k)[0][:, -1])
+    for label, plane in (("CNN plane", flat),
+                         ("NaN row", flat.at[0, 7].set(jnp.nan))):
+        got_k, want_k = (np.asarray(jax.jit(f)(plane)).view(np.int32)
+                         for f in (lambda f: kops.plane_kth_magnitude(f, k),
+                                   top_kth))
+        same = got_k.tobytes() == want_k.tobytes()
+        print(f"cnn: k-th magnitude kernel vs lax.top_k on the {label}: "
+              f"bitwise {same}", flush=True)
+        check(same, f"k-th magnitude kernel differs from lax.top_k on the "
+              f"{label}")
 
     # (d) the quantize and commit kernels on the same plane, whose tile rows
     # leave a ragged last block, against repro.kernels.ref

@@ -266,14 +266,20 @@ class TopK(Transport):
         k = _k_of(self.ratio, spec.d)
         if k >= spec.d:
             return flat
+        from repro.kernels import ops as kops
+        from repro.kernels import plane_ops
+
+        on_tpu = kops._on_tpu()
         mag = jnp.abs(flat)
         # the k-th magnitude over the padded plane equals the k-th over the
         # valid region (padding is zero and k <= d), so no masking is needed
         # and selected padding zeros stay zero
-        kth = jax.lax.top_k(mag, k)[0][:, -1]
-        from repro.kernels import ops as kops
-
-        if kops._on_tpu():
+        if on_tpu and plane_ops.kth_fits(flat.shape[1], flat.dtype):
+            # bisection on the bit patterns, each row in VMEM: no sort
+            kth = kops.plane_kth_magnitude(flat, k)
+        else:
+            kth = jax.lax.top_k(mag, k)[0][:, -1]
+        if on_tpu:
             # fused select+scatter pass over the tiled plane
             return kops.plane_threshold_select(flat, kth)
         return jnp.where(mag >= kth[:, None], flat, 0)

@@ -97,11 +97,23 @@ def fused_local_update_step(reg, eta, t, z_hat, grads, c):
 # ---------------------------------------------------------------------------
 
 
+def plane_kth_magnitude(flat_plane, k, *, interpret=None):
+    """Per-client k-th largest magnitude of a float32 (clients, d_pad)
+    plane, bitwise ``lax.top_k(|x|, k)[0][:, -1]``, found by bisection on
+    the bit patterns with each client's row in VMEM (rows must pass
+    :func:`plane_ops.kth_fits`).  ``k``: an int or (clients,) ranks."""
+    interpret = (not _on_tpu()) if interpret is None else interpret
+    k = jnp.broadcast_to(jnp.asarray(k, jnp.int32), flat_plane.shape[:1])
+    return plane_ops.kth_magnitude_3d(_as_tiles(flat_plane), k,
+                                      interpret=interpret)
+
+
 def plane_threshold_select(flat_plane, thresh, *, interpret=None,
                            block_rows=fused_prox.BLOCK_ROWS):
     """Global top-k select on a (clients, d_pad) plane: keep coordinates
     whose magnitude reaches the per-client ``thresh``, zero the rest (one
-    fused pass; the k-th values come from one ``lax.top_k`` on the plane).
+    fused pass; the k-th values come from :func:`plane_kth_magnitude` on
+    the chip, from one ``lax.top_k`` elsewhere).
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
     out = plane_ops.threshold_select_3d(_as_tiles(flat_plane), thresh,

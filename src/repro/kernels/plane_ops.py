@@ -5,10 +5,13 @@ Every kernel here operates on the ``(clients, d_pad)`` layout of
 so the communication and aggregation hot paths run as single tiled passes
 instead of one small op per pytree leaf:
 
-  * :func:`threshold_select_3d` -- the select+scatter half of **global**
-    top-k sparsification: given the per-client k-th magnitude (one
-    ``lax.top_k`` reduction on the plane), zero everything below it in one
-    fused pass.  Reads x once, writes the sparsified plane once.
+  * :func:`kth_magnitude_3d` -- the search half of **global** top-k
+    sparsification: each client's k-th largest magnitude, found exactly by
+    bisecting on its float32 bit pattern with the client's whole row
+    resident in VMEM (no sort, no indices).
+  * :func:`threshold_select_3d` -- the select+scatter half: given the
+    per-client k-th magnitude, zero everything below it in one fused pass.
+    Reads x once, writes the sparsified plane once.
   * :func:`quantize_3d` -- fused stochastic uniform quantization
     (scale, level, stochastic round, dequantize in one pass).  Uniform
     draws are an input, so the kernel is deterministic given them and
@@ -23,10 +26,12 @@ TPU mapping: planes are reshaped to ``(clients, rows, 128)`` lanes; each
 grid step processes one client's ``(block_rows, 128)`` tile resident in
 VMEM (the commit kernel processes all clients of one tile column, since it
 reduces over them, so its block height shrinks as the client count grows:
-see :func:`commit_block_rows`).  ``rows`` need not divide by the block
-height: the grid is ``cdiv(rows, block_rows)`` and the last block is
-ragged (its out-of-range rows are never written).  Per-client scalars
-(thresholds, quantization scales, commit weights) ride in SMEM.  Public
+see :func:`commit_block_rows`; the k-th magnitude kernel holds one
+client's whole row, so its block is never ragged and no padding row
+enters a count).  ``rows`` need not divide by the block height: the grid
+is ``cdiv(rows, block_rows)`` and the last block is ragged (its
+out-of-range rows are never written).  Per-client scalars (ranks,
+thresholds, quantization scales, commit weights) ride in SMEM.  Public
 entry points with automatic interpret-mode selection live in
 :mod:`repro.kernels.ops`.
 """
@@ -44,6 +49,56 @@ from repro.kernels.fused_prox import BLOCK_ROWS, LANES
 #: VMEM bytes one commit input block may take; Pallas double-buffers it,
 #: so the kernel's footprint stays well inside v5e's 16 MiB scoped default
 COMMIT_VMEM_BYTES = 4 << 20
+#: VMEM bytes of one client's row that :func:`kth_magnitude_3d` holds
+#: whole: double-buffered, with the loop's temporaries beside it, it stays
+#: inside the same 16 MiB default
+KTH_VMEM_BYTES = 2 << 20
+#: float32 bits below the sign: ``bits & ABS_BITS`` is ``|x|`` as int32
+ABS_BITS = 0x7FFFFFFF
+
+
+def kth_fits(d_pad: int, dtype) -> bool:
+    """Whether :func:`kth_magnitude_3d` takes a client's plane row of
+    ``d_pad`` elements: float32, whole in VMEM."""
+    return jnp.dtype(dtype) == jnp.float32 and d_pad * 4 <= KTH_VMEM_BYTES
+
+
+def _kth_kernel(k_ref, x_ref, out_ref):
+    i = pl.program_id(0)  # client
+    k = k_ref[i]
+
+    def step(j, t):
+        # the largest t with count(|x| bits >= t) >= k, one bit at a time;
+        # for non-negative float32 the int32 order is the value order, and
+        # NaN bits rank above inf, as lax.top_k ranks them
+        cand = t | jax.lax.shift_left(jnp.int32(1),
+                                      (30 - j).astype(jnp.int32))
+        bits = jax.lax.bitcast_convert_type(x_ref[0], jnp.int32) & ABS_BITS
+        n_ge = jnp.sum(bits >= cand, dtype=jnp.int32)
+        return jnp.where(n_ge >= k, cand, t)
+
+    out_ref[i] = jax.lax.fori_loop(0, 31, step, jnp.int32(0))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kth_magnitude_3d(x, k, *, interpret=False):
+    """Core call on ``x``: (n, R, 128) float32; ``k``: (n,) int32 per-client
+    ranks (1 <= k <= R * 128) -> (n,) float32 k-th largest ``|x|`` per
+    client, bitwise ``lax.top_k(|x|, k)[0][:, -1]``.  One grid step per
+    client, whose block is its whole row."""
+    n, rows, lanes = x.shape
+    assert lanes == LANES and x.dtype == jnp.float32, (x.shape, x.dtype)
+    bits = pl.pallas_call(
+        _kth_kernel,
+        grid=(n,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                  pl.BlockSpec((1, rows, LANES), lambda i: (i, 0, 0))],
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        interpret=interpret,
+        name="topk-kth",
+    )(k.astype(jnp.int32), x)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def _threshold_kernel(thresh_ref, x_ref, out_ref):

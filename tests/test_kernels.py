@@ -166,6 +166,8 @@ def _kernel_calls():
     qkv = jnp.ones((1, 1, 128, 64))
     return {
         "threshold_select": (plane_ops.threshold_select_3d, (x, t), {}),
+        "topk-kth": (plane_ops.kth_magnitude_3d,
+                     (x.astype(jnp.float32), jnp.ones((2,), jnp.int32)), {}),
         "quantize_plane": (plane_ops.quantize_3d, (x, x, t, 4), {}),
         "weighted_commit": (plane_ops.weighted_commit_3d, (x, t), {}),
         "fused_prox_update": (fused_prox.fused_local_update_2d,
@@ -175,9 +177,9 @@ def _kernel_calls():
     }
 
 
-@pytest.mark.parametrize("name", ["threshold_select", "quantize_plane",
-                                  "weighted_commit", "fused_prox_update",
-                                  "flash_attention"])
+@pytest.mark.parametrize("name", ["threshold_select", "topk-kth",
+                                  "quantize_plane", "weighted_commit",
+                                  "fused_prox_update", "flash_attention"])
 def test_pallas_call_carries_its_name(name):
     """Each ``pallas_call`` is named: lowered in interpret mode, its body
     sits under a scope of that name."""

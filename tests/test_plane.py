@@ -421,6 +421,111 @@ def test_threshold_select_kernel_matches_ref(shape):
                                   np.asarray(ref.plane_threshold_select(x, th)))
 
 
+def _kth_case(name):
+    """(plane, k) of one case of the k-th magnitude kernel's test."""
+    rng = np.random.default_rng(4)
+    x = rng.normal(size=(3, 1024)).astype(np.float32)
+    k = 100
+    if name == "tied":
+        x = np.round(x * 4) / 4
+    elif name == "all_zero":
+        x[1] = 0.0
+    elif name == "negative_zero":
+        x[0] = -0.0
+        x[1, ::2] = -0.0
+    elif name == "denormal":
+        x = (x * 1e-39).astype(np.float32)
+    elif name == "inf":
+        x[0, 3] = np.inf
+        x[2, :200] = -np.inf
+    elif name == "nan":
+        x[0, 5] = np.nan
+        x[1, :300] = -np.nan
+    elif name.startswith("k="):
+        k = {"k=1": 1, "k=d-1": x.shape[1] - 1, "k=d": x.shape[1]}[name]
+    elif name == "lane_padded":  # d = 1000 valid of d_pad = 1024
+        x[:, 1000:] = 0.0
+        k = 999
+    elif name == "cnn_rows":  # the CNN's plane: 879 rows of 128 lanes
+        x = rng.normal(size=(2, 879 * 128)).astype(np.float32)
+        x[:, 112_394:] = 0.0
+        k = 11_239
+    return jnp.asarray(x), k
+
+
+@pytest.mark.parametrize("name", [
+    "random", "tied", "all_zero", "negative_zero", "denormal", "inf", "nan",
+    "k=1", "k=d-1", "k=d", "lane_padded", "cnn_rows"])
+def test_kth_magnitude_kernel_matches_top_k_bitwise(name):
+    """The bisection's k-th magnitude is ``lax.top_k``'s, bit for bit: ties,
+    signed zeros, denormals, inf and NaN (both rank it highest) included."""
+    x, k = _kth_case(name)
+    got = ops.plane_kth_magnitude(x, k, interpret=True)
+    want = jax.lax.top_k(jnp.abs(x), k)[0][:, -1]
+    np.testing.assert_array_equal(np.asarray(got).view(np.int32),
+                                  np.asarray(want).view(np.int32))
+
+
+def _kernels_in_interpret_mode(monkeypatch):
+    """Steer ``TopK.apply_flat`` down its chip path, with the plane kernels
+    interpreted on the CPU."""
+    import functools
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    for name in ("plane_kth_magnitude", "plane_threshold_select"):
+        monkeypatch.setattr(ops, name, functools.partial(getattr(ops, name),
+                                                         interpret=True))
+
+
+def test_global_topk_kernel_path_matches_top_k_path(monkeypatch):
+    """Global top-k through the k-th magnitude and select kernels equals
+    the ``lax.top_k`` path element for element, error feedback included,
+    over rounds whose residuals tie and grow."""
+    rng = np.random.default_rng(5)
+    msg = {"w": jnp.zeros((4, 30, 20), jnp.float32),
+           "b": jnp.zeros((4, 7), jnp.float32)}
+    spec = pln.SegmentSpec.from_tree(msg, batch_dims=1)
+    tr = TopK(ratio=0.1, granularity="global")
+    planes = [pln.flatten(spec, {
+        "w": jnp.asarray(np.round(rng.normal(size=(4, 30, 20)) * 8) / 8,
+                         jnp.float32),
+        "b": jnp.asarray(rng.normal(size=(4, 7)), jnp.float32)})
+        for _ in range(3)]
+
+    def rounds():
+        state = jnp.zeros((4, spec.d_pad), jnp.float32)
+        out = []
+        for flat in planes:
+            hat, state = tr.compress_plane(state, flat, None, spec)
+            out.append((np.asarray(hat), np.asarray(state)))
+        return out
+
+    want = rounds()
+    _kernels_in_interpret_mode(monkeypatch)
+    for (hat, state), (hat_w, state_w) in zip(rounds(), want):
+        np.testing.assert_array_equal(hat, hat_w)
+        np.testing.assert_array_equal(state, state_w)
+
+
+@pytest.mark.parametrize("fits", [True, False])
+def test_global_topk_routes_rows_over_the_vmem_budget_to_top_k(monkeypatch,
+                                                                fits):
+    """On the chip a row within ``KTH_VMEM_BYTES`` takes the bisection
+    kernel; a larger one keeps ``lax.top_k``."""
+    from repro.kernels import plane_ops
+
+    _kernels_in_interpret_mode(monkeypatch)
+    spec = pln.SegmentSpec.from_tree(
+        {"w": jnp.zeros((2, 1000), jnp.float32)}, batch_dims=1)
+    monkeypatch.setattr(plane_ops, "KTH_VMEM_BYTES",
+                        spec.d_pad * 4 - (0 if fits else 1))
+    jaxpr = str(jax.make_jaxpr(
+        lambda f: TopK(ratio=0.1, granularity="global").apply_flat(
+            f, None, spec))(jnp.ones((2, spec.d_pad), jnp.float32)))
+    assert ("kth_magnitude_3d" in jaxpr) == fits
+    assert ("top_k" in jaxpr) == (not fits)
+
+
 # 640 lanes are 5 tile rows: block_rows=2 leaves a ragged last block
 @pytest.mark.parametrize("block_rows", [1, 2])
 @pytest.mark.parametrize("bits", [4, 8])

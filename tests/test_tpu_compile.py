@@ -86,6 +86,19 @@ def test_threshold_select_compiles(one_chip, n_clients):
     assert "tpu_custom_call" in txt
 
 
+@pytest.mark.parametrize("n_clients,rows", [
+    (10, CNN_ROWS), (64, CNN_ROWS),
+    (2, plane_ops.KTH_VMEM_BYTES // (4 * plane_ops.LANES))])
+def test_kth_magnitude_compiles(one_chip, n_clients, rows):
+    """Each client's whole row in VMEM: the CNN's 879 rows, and the
+    largest row the VMEM budget admits."""
+    txt = _compiled_text(
+        plane_ops.kth_magnitude_3d,
+        _shape(one_chip, (n_clients, rows, plane_ops.LANES)),
+        _shape(one_chip, (n_clients,), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
 @pytest.mark.parametrize("n_clients", [10, 64])
 def test_quantize_compiles(one_chip, n_clients):
     x = _shape(one_chip, (n_clients, CNN_ROWS, plane_ops.LANES))
