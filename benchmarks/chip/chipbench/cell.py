@@ -8,6 +8,12 @@ the readings the comparison needs.  The window then calls the same engine
 on the same state, chunk after chunk, until ``--seconds`` have passed.
 Once it has closed, the device's peak memory is read, the program's state
 is freed, and the plain reference replays those three steps.
+
+The harness holds no device copy of the initial weights beside the
+program's state: it makes them from the seed for ``engine.init`` and drops
+them, makes them again (the same jitted call, so bitwise the same) to
+read the program's change after step 3, and once more, into host memory,
+for the reference.
 """
 from __future__ import annotations
 
@@ -22,7 +28,7 @@ from functools import partial
 
 import numpy as np
 
-from chipbench import check, traffic as traffic_mod
+from chipbench import check, layers, traffic as traffic_mod
 from chipbench import trace as trace_mod
 
 STEPS = 3  # steps the reference replays
@@ -73,6 +79,12 @@ def seed_key(seed: int):
 
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                               (seed >> 32) & 0xFFFFFFFF)
+
+
+def weights(init_fn, seed: int):
+    """The initial weights of a run, on the device: ``init_fn`` (one jitted
+    call) of the seed's key."""
+    return init_fn(seed_key(seed))
 
 
 @dataclass
@@ -212,13 +224,14 @@ def run_cell(reg, name: str, seed: int, seconds: float, traced: bool,
         # -- set-up: data, weights, engine, the first three steps ----------
         data = traffic_mod.make(traffic, config, seed)
         phases["data"] = time.perf_counter()
-        params0 = jax.jit(lambda k: ref_mod.init_params(k, config))(
-            seed_key(seed))
+        init_fn = jax.jit(lambda k: ref_mod.init_params(k, config))
+        params0 = weights(init_fn, seed)
         jax.block_until_ready(params0)
         phases["weights"] = time.perf_counter()
         grad_fn = reg.program(config["family"]).grad_fn(config)
         engine = build_engine(config, traffic, grad_fn)
-        state = engine.init(params0)
+        state = engine.init(params0)  # shares no buffer with params0
+        del params0
         feed = Feed(STEPS * chunk, annotate)
         if traffic["supplier"] == "host_per_round":
             supplier = feed.host_callable(data)
@@ -243,7 +256,9 @@ def run_cell(reg, name: str, seed: int, seconds: float, traced: bool,
             if step == 0:
                 prog["c_norms"] = dprox.leaf_norms(state.c)
             phases[f"step{step + 1}"] = time.perf_counter()
-        prog["change_norms"] = dprox.diff_norms(state.x_bar, params0)
+        x0 = weights(init_fn, seed)
+        prog["change_norms"] = dprox.diff_norms(state.x_bar, x0)
+        del x0
         marks = list(phases.items())
         log("set-up phases (s): " + ", ".join(
             f"{k} {t1 - t0:.3f}" for (_k, t0), (k, t1)
@@ -286,12 +301,18 @@ def run_cell(reg, name: str, seed: int, seconds: float, traced: bool,
             failed = max(failed, chunk)
         memory = _memory(devices)
         log(f"window: {rounds} rounds in {len(chunk_s)} chunks, "
-            f"{window_s:.3f}s; {compiles} compiles; {failed} failed")
+            f"{window_s:.3f}s; {compiles} compiles; {failed} failed; device "
+            f"peak {memory.get('in_use_bytes')} bytes in use + "
+            f"{memory.get('reserved_bytes')} reserved")
 
         # -- the reference, once the program's state is freed ----------------
         del state, engine, supplier
         gc.collect()
         t_ref = time.perf_counter()
+        # in host memory, C-contiguous (read back from the TPU, an array
+        # can keep the device's order of axes in its strides)
+        params0 = jax.tree_util.tree_map(np.ascontiguousarray,
+                                         weights(init_fn, seed))
         if traffic["supplier"] == "device_cache":
             # the program's supplier drew these rounds: the reference takes
             # the harness's own draw, and every row served is checked
@@ -315,8 +336,10 @@ def run_cell(reg, name: str, seed: int, seconds: float, traced: bool,
             found[vname] = check.numbers(alt, ref)
             readings[vname] = alt
         correct, checks = check.judge(nums, reg.limits(name))
+        peak = _memory(devices).get("peak_bytes")
         log(f"reference: {time.perf_counter() - t_ref:.1f}s; "
-            f"{nums['leaves_left_out']} leaves left out")
+            f"{nums['leaves_left_out']} leaves left out; device peak "
+            f"{peak} bytes with the window's")
         log("readings: " + json.dumps({"program": prog, "reference": ref}))
 
     ctx = Context(
@@ -339,7 +362,7 @@ def run_cell(reg, name: str, seed: int, seconds: float, traced: bool,
               "failed": failed, "metrics": metrics, "device": device}
     if traced:
         device["busy_s"], device["window_s"] = _busy(trace)
-        bd = trace_mod.breakdown(trace)
+        bd = layers.breakdown(trace)
         if bd is not None:
             result["breakdown"] = bd
     if variants:
@@ -351,12 +374,15 @@ def run_cell(reg, name: str, seed: int, seconds: float, traced: bool,
 
 
 def _read_trace(trace_dir: str):
+    """The window's trace reduced with the program's spans and the device
+    ops' layer scopes (``layers.LayerTrace``, a ``trace.Trace``)."""
     import glob
     import shutil
 
     paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
     try:
-        return trace_mod.from_xplane(paths[0]) if paths else trace_mod.Trace()
+        return (layers.from_xplane(paths[0]) if paths
+                else layers.LayerTrace())
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
 
@@ -381,9 +407,10 @@ def _memory(devices) -> dict:
         stats = d.memory_stats() or {}
         if "peak_bytes_in_use" not in stats:
             continue
-        peak = int(stats["peak_bytes_in_use"]) + int(
-            stats.get("peak_bytes_reserved", 0))
-        if peak >= best.get("peak_bytes", -1):
-            best = {"peak_bytes": peak,
-                    "limit_bytes": int(stats.get("bytes_limit", 0))}
+        in_use = int(stats["peak_bytes_in_use"])
+        reserved = int(stats.get("peak_bytes_reserved", 0))
+        if in_use + reserved >= best.get("peak_bytes", -1):
+            best = {"peak_bytes": in_use + reserved,
+                    "limit_bytes": int(stats.get("bytes_limit", 0)),
+                    "in_use_bytes": in_use, "reserved_bytes": reserved}
     return best

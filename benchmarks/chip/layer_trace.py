@@ -86,20 +86,19 @@ def main(argv=None) -> int:
     reduce = tr.from_xplane
 
     def capture(path):
-        # this tool's own reading of the run's file, beside the harness's
-        base = reduce(path)
-        kept["layers"] = layers.from_xplane(path, base)
-        if args.xplane:
-            os.makedirs(os.path.dirname(os.path.abspath(args.xplane)),
-                        exist_ok=True)
-            with open(path, "rb") as src, gzip.open(args.xplane, "wb") as dst:
-                shutil.copyfileobj(src, dst)
-        return base
+        # the profiler's whole file, before the harness removes it
+        os.makedirs(os.path.dirname(os.path.abspath(args.xplane)),
+                    exist_ok=True)
+        with open(path, "rb") as src, gzip.open(args.xplane, "wb") as dst:
+            shutil.copyfileobj(src, dst)
+        return reduce(path)
 
-    tr.from_xplane = capture
+    if args.xplane:
+        tr.from_xplane = capture
     try:
         res = run_cell(Registry(), args.workload, args.seed % 2 ** 64,
-                       args.seconds, True, T_START)
+                       args.seconds, True, T_START,
+                       trace_sink=lambda t: kept.update(layers=t))
     finally:
         tr.from_xplane = reduce
     lt = kept["layers"]

@@ -317,3 +317,60 @@ def test_recorded_layer_trace(name):
     assert (reg.metric_reader("device_idle_share")(ctx),
             reg.metric_reader("supply_ms_per_round")(ctx)) == pytest.approx(
         want["harness"], rel=1e-9)
+
+
+def _reader_context(trace):
+    from chipbench.cell import Context
+
+    return Context(chips=1, peaks={"bf16_flops": 197e12,
+                                   "hbm_bytes_per_s": 819e9},
+                   setup_s=1.0, window_s=0.5, chunk_s=[0.25, 0.25], rounds=1,
+                   samples=10, flops_per_sample=1e9, compiles_in_window=0,
+                   memory={"peak_bytes": 4e9, "limit_bytes": 16e9},
+                   trace=trace)
+
+
+@pytest.mark.parametrize("name", sorted(
+    p.stem for p in DATA.glob("*.json")))
+def test_every_reader_reads_a_layer_trace_as_the_plain_one(name):
+    """The harness keeps the traced window as a ``LayerTrace``: each
+    per-layer reader of ``BENCHMARK.json`` reads the same number from it
+    as from the plain reduction of the same recorded file."""
+    from chipbench.registry import Registry
+
+    reg = Registry()
+    plain = tr.load(str(DATA / f"{name}.json"))
+    layered = layers.load(str(DATA / f"{name}.json"))
+    assert isinstance(layered, tr.Trace)
+    found = 0
+    for spec in reg.benchmark["per_layer"]:
+        read = reg.metric_reader(spec["name"])
+        want = read(_reader_context(plain))
+        assert read(_reader_context(layered)) == want, spec["name"]
+        found += want is not None
+    assert found >= 3  # the trace's readers found something to read
+
+
+def test_the_harness_keeps_the_layer_scopes(tmp_path):
+    """``run_cell``'s reduction of a traced window is a ``LayerTrace``
+    with the program's spans and the device ops' scopes (a CPU profile)."""
+    import jax
+
+    from chipbench import cell
+    from repro.obs import trace as obs_trace
+
+    f = jax.jit(lambda x: x * 2)
+    x = jax.numpy.ones(3)
+    f(x).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("bench/window"):
+            with obs_trace.span("exec/chunk", "exec"):
+                f(x).block_until_ready()
+    t = cell._read_trace(str(tmp_path))
+    assert isinstance(t, layers.LayerTrace)
+    assert t.spans("bench/window")
+    assert [e[0] for e in t.program_spans()] == ["exec/chunk"]
+    assert not tmp_path.exists()  # the profiler's files are removed
+    empty = tmp_path.parent / "no_trace"
+    empty.mkdir()
+    assert isinstance(cell._read_trace(str(empty)), layers.LayerTrace)
